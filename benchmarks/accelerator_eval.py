@@ -220,6 +220,8 @@ def run(n_graphs: int = 32, datasets=None, log=print) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=32)
     ap.add_argument("--datasets", nargs="*", default=None)

@@ -272,6 +272,8 @@ def check_acceptance(res: dict):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="single (load, dose) point + all robustness "

@@ -61,4 +61,6 @@ def run(n_synth: int = 12, n_model_calls: int = 400, log=print) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     run()

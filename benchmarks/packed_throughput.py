@@ -156,6 +156,8 @@ def run_all(convs=None, dataset: str = "qm9",
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--convs", nargs="+",
                     default=list(Cv.CONV_TYPES),

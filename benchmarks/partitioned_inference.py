@@ -227,6 +227,8 @@ def check_acceptance(res: dict):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--worker", type=int, default=None,
                     help=argparse.SUPPRESS)   # internal: one sweep point

@@ -63,6 +63,8 @@ def run(n: int = 400, seed: int = 0, measured: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=400)
     ap.add_argument("--measured", action="store_true")

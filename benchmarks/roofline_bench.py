@@ -53,4 +53,6 @@ def run(log=print) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     run()

@@ -66,4 +66,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

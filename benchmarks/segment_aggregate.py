@@ -102,6 +102,8 @@ def run(batch_graphs: int = 32, feat_dim: int = 64, repeats: int = 5,
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch-graphs", type=int, default=32)
     ap.add_argument("--feat-dim", type=int, default=64)

@@ -216,6 +216,8 @@ def check_acceptance(res: dict):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="single-load run + parity/p99/throughput gates "

@@ -68,7 +68,11 @@ _DEFAULT_GATHER_MODE = "dma"
 _DEFAULT_INTERPRET: bool | None = None
 
 
-def _resolve_interpret(interpret: bool | None) -> bool:
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one place a Pallas kernel's ``interpret`` flag is decided:
+    an explicit bool wins, then the process default
+    (``set_default_backend(interpret=...)``), then the backend — Mosaic
+    compiles on a TPU, and everything else interprets."""
     if interpret is None:
         interpret = _DEFAULT_INTERPRET
     if interpret is None:
@@ -250,7 +254,7 @@ def segment_aggregate(agg: str, messages, seg_ids, num_segments: int,
             messages, seg_ids, valid, num_segments=num_segments, agg=agg,
             edge_block=edge_block or _DEFAULT_EDGE_BLOCK,
             node_block=node_block or _DEFAULT_NODE_BLOCK,
-            interpret=_resolve_interpret(interpret),
+            interpret=resolve_interpret(interpret),
             gather_mode=gather_mode or _DEFAULT_GATHER_MODE)
         return out if dequant is None else out * dequant
     if lp is not None and lp.compute == "int8":
@@ -319,7 +323,7 @@ def segment_softmax(logits, seg_ids, num_segments: int, valid=None, *,
         return _pallas_segment_softmax(
             logits, seg_ids, valid, num_segments=num_segments,
             edge_block=edge_block or _DEFAULT_EDGE_BLOCK,
-            interpret=_resolve_interpret(interpret))
+            interpret=resolve_interpret(interpret))
     seg_ids = jnp.asarray(seg_ids, jnp.int32)
     ok = (seg_ids >= 0) & (seg_ids < num_segments)
     if valid is not None:
@@ -392,7 +396,7 @@ def gather_aggregate(agg: str, x, src, dst, num_segments: int, valid=None,
             x, src, dst, valid, scale, num_segments=num_segments, agg=agg,
             edge_block=edge_block or _DEFAULT_EDGE_BLOCK,
             node_block=node_block or _DEFAULT_NODE_BLOCK,
-            interpret=_resolve_interpret(interpret),
+            interpret=resolve_interpret(interpret),
             gather_mode=gather_mode or _DEFAULT_GATHER_MODE)
     if lp is not None and lp.compute == "int8":
         from repro.core import quantization as Q

@@ -288,41 +288,41 @@ class ResidencyPlan:
     depth: int            # layers fused per kernel launch (min(req, L))
     fmax: int             # padded table width (lane-aligned max dim)
     vmem_required: int    # bytes at the widest point of the fused group
-    vmem_budget: int      # bytes the planner allows (frac * target VMEM)
+    vmem_budget: int      # bytes the kernel may use (its vmem_limit_bytes)
     reason: str
 
 
 def residency_plan(layer_dims, node_budget: int, conv: str,
                    fusion_depth: int, *, quantized: bool = False,
-                   edge_block: int = 128, vmem_bytes: int | None = None,
-                   vmem_frac: float = 0.75) -> ResidencyPlan:
+                   vmem_bytes: int | None = None) -> ResidencyPlan:
     """VMEM-budget rule deciding when multi-layer residency is legal.
 
     layer_dims: [(in_dim, out_dim), ...] for the conv stack;
     node_budget: packed-batch node-table rows; quantized: a non-fp32
-    policy adds the quantized shadow table. The resident working set at
-    the widest point is the fp32 table, the input block, the aggregate
-    accumulator (and the shadow when quantized) — each
-    ``node_budget * fmax * 4`` bytes with ``fmax`` the lane-aligned max
-    layer width — plus the mean-count column and the double-buffered
-    per-layer weight/scale blocks. Legal only for ``RESIDENT_CONVS``
-    (linear phi, one scalar per edge) at ``fusion_depth > 1``, and only
-    when the working set fits ``vmem_frac`` of the target's VMEM
-    (default ``core.project.TPUTarget.vmem_bytes``) — the remaining
-    fraction is headroom for Mosaic's own spills."""
-    if vmem_bytes is None:
-        from repro.core.project import TPUTarget
-        vmem_bytes = int(TPUTarget().vmem_bytes)
-    budget = int(vmem_bytes * vmem_frac)
+    policy adds the quantized shadow table. The working set is counted
+    in Mosaic's layout: the pipelined (N, Fmax) input and output blocks
+    are double-buffered, the aggregate accumulator (and the shadow when
+    quantized) are single scratch tables, every (N, 1) column (self
+    scale and node mask, double-buffered, plus the mean count) occupies
+    a full 128-lane row per node, and the per-layer weight and bias
+    blocks are double-buffered. ``fmax`` is the lane-aligned max layer
+    width. Legal only for ``RESIDENT_CONVS`` (linear phi, one scalar per
+    edge) at ``fusion_depth > 1``, and only when the working set fits
+    ``vmem_bytes`` — by default ``kernels.tpu.VMEM_LIMIT_BYTES``, the
+    scoped-VMEM limit the kernel compiles with, so a legal plan is one
+    Mosaic accepts (tests/test_tpu_compile.py compiles the largest
+    legal stack). The edge streams ride SMEM and do not enter the
+    count."""
+    from repro.kernels.tpu import LANES, VMEM_LIMIT_BYTES
+    budget = int(VMEM_LIMIT_BYTES if vmem_bytes is None else vmem_bytes)
     depth = max(1, min(int(fusion_depth), len(layer_dims)))
     fmax = max(max(d) for d in layer_dims)
-    fmax = -(-fmax // 128) * 128
-    tables = 3 + (1 if quantized else 0)       # x0, xout, aggr[, xq]
+    fmax = -(-fmax // LANES) * LANES
+    tables = 2 + 2 + 1 + (1 if quantized else 0)   # x0, out, aggr[, xq]
+    columns = 2 + 2 + 1                         # self scale, mask, count
     required = (tables * node_budget * fmax * 4
-                + node_budget * 4               # mean count column
-                + 2 * node_budget * 4           # self-scale + node mask
-                + 2 * (3 * fmax * fmax + fmax + 128) * 4  # dbl-buf weights
-                + 2 * edge_block * 4)           # dbl-buf edge scales
+                + columns * node_budget * LANES * 4
+                + 2 * (3 * fmax * fmax + 8 * fmax) * 4)  # weights, bias
     if conv not in RESIDENT_CONVS:
         return ResidencyPlan(False, depth, fmax, required, budget,
                              f"conv {conv!r} not in {RESIDENT_CONVS}")

@@ -383,7 +383,7 @@ def apply_packed_resident(params, cfg: GNNModelConfig, batch: dict,
     n = x.shape[0]
     plan = C.residency_plan([(c.in_dim, c.out_dim) for c in ccs], n,
                             cfg.gnn_conv, fusion_depth,
-                            quantized=pol is not None, edge_block=eb,
+                            quantized=pol is not None,
                             vmem_bytes=vmem_bytes)
     if quant is not None or not plan.legal:
         return apply_packed(params, cfg, batch, quant, policy)
@@ -432,7 +432,7 @@ def apply_packed_resident(params, cfg: GNNModelConfig, batch: dict,
             jnp.stack(bias), jnp.asarray(qps, jnp.float32),
             kind=cfg.gnn_conv, activation=cfg.gnn_activation,
             edge_block=eb,
-            interpret=agg_mod._resolve_interpret(interpret),
+            interpret=agg_mod.resolve_interpret(interpret),
             has_skip=cfg.gnn_skip_connection,
             quantized=pol is not None)
 
@@ -478,7 +478,6 @@ def make_sharded_apply(cfg: GNNModelConfig, mesh,
     first call, like ``apply_packed`` under jit. Hold on to the returned
     callable across waves so XLA compiles exactly once.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import (graph_batch_sharding,
@@ -488,9 +487,9 @@ def make_sharded_apply(cfg: GNNModelConfig, mesh,
         batch = {k: v[0] for k, v in batch.items()}
         return apply_packed(params, cfg, batch, quant, policy)[None]
 
-    fn = shard_map(per_shard, mesh=mesh,
-                   in_specs=(P(), P("data")), out_specs=P("data"),
-                   check_rep=False)
+    fn = jax.shard_map(per_shard, mesh=mesh,
+                       in_specs=(P(), P("data")), out_specs=P("data"),
+                       check_vma=False)
     return jax.jit(fn, in_shardings=(replicated(mesh),
                                      graph_batch_sharding(mesh)))
 
@@ -554,7 +553,6 @@ def make_partitioned_apply(cfg: GNNModelConfig, mesh,
     (out_dim,) output row, node tasks the (out_rows, F) global-order
     node table.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import (graph_batch_sharding,
@@ -581,9 +579,9 @@ def make_partitioned_apply(cfg: GNNModelConfig, mesh,
                              return_node_features=True)
         return feats[None]
 
-    conv = shard_map(per_device, mesh=mesh,
-                     in_specs=(P(), P("data")), out_specs=P("data"),
-                     check_rep=False)
+    conv = jax.shard_map(per_device, mesh=mesh,
+                         in_specs=(P(), P("data")), out_specs=P("data"),
+                         check_vma=False)
     conv = jax.jit(conv, in_shardings=(replicated(mesh),
                                        graph_batch_sharding(mesh)))
 

@@ -122,7 +122,7 @@ class Project:
         self.gather_mode = gather_mode
         # multi-layer VMEM residency: fusion_depth > 1 asks for the
         # resident conv stack; convs.residency_plan decides legality
-        # against the target's VMEM at gen_hw_model time
+        # against the kernels' scoped-VMEM limit at gen_hw_model time
         if fusion_depth < 1:
             raise ValueError(f"fusion_depth must be >= 1, "
                              f"got {fusion_depth}")
@@ -184,8 +184,7 @@ class Project:
             [(cfg.conv_cfg(i).in_dim, cfg.conv_cfg(i).out_dim)
              for i in range(cfg.gnn_num_layers)],
             self.node_budget, cfg.gnn_conv, self.fusion_depth,
-            quantized=not policy.is_fp32, edge_block=self.edge_block,
-            vmem_bytes=int(self.target.vmem_bytes))
+            quantized=not policy.is_fp32)
         resident = (self.residency.legal and self.fusion_depth > 1
                     and backend == "pallas" and quant is None)
         self.residency_engaged = resident

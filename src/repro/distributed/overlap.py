@@ -22,7 +22,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _ring_perm(n: int):
@@ -96,13 +95,13 @@ def make_overlapped_ops(mesh: Mesh, axis: str = "model"):
     """shard_map-wrapped (ag_matmul, matmul_rs) bound to a mesh axis."""
     other = tuple(a for a in mesh.axis_names if a != axis)
 
-    ag = shard_map(
+    ag = jax.shard_map(
         functools.partial(ag_matmul_local, axis_name=axis), mesh=mesh,
         in_specs=(P(axis, None), P(None, None)),
-        out_specs=P(None, None), check_rep=False)
+        out_specs=P(None, None), check_vma=False)
 
-    rs = shard_map(
+    rs = jax.shard_map(
         functools.partial(matmul_rs_local, axis_name=axis), mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
-        out_specs=P(axis, None), check_rep=False)
+        out_specs=P(axis, None), check_vma=False)
     return ag, rs

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.aggregations import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -56,7 +58,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q: (BH, Sq, D); k, v: (BH, Skv, D) -> (BH, Sq, D)."""
     bh, sq, d = q.shape
     skv, dv = k.shape[1], v.shape[-1]
@@ -80,5 +82,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

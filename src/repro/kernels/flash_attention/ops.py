@@ -14,7 +14,7 @@ from repro.kernels.flash_attention.ref import attention_ref
                                    "use_pallas", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, use_pallas: bool = True,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q/k/v: (B, H, S, D) or (BH, S, D)."""
     squeeze = q.ndim == 4
     if squeeze:

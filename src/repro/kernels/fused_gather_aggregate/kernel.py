@@ -46,11 +46,14 @@ Two generations live here (docs/KERNELS.md has the full contract):
   edge block and re-sweeping the edge stream once per node tile.
 * ``fused_gather_aggregate_v2_pallas`` — the **DMA gather**
   (``gather_mode="dma"``, the default): the src/dst id streams are
-  scalar-prefetched into SMEM (PrefetchScalarGridSpec), node rows are
-  gathered by dynamic slice, and the per-edge scale stream is
-  double-buffered HBM->VMEM by explicit async copies — O(EB * F) work
-  per edge block, one sweep over the edge stream, no one-hot ever
-  materialized.
+  scalar-prefetched into SMEM (PrefetchScalarGridSpec) together with
+  the per-edge scale stream, node rows are gathered by dynamic slice —
+  O(EB * F) work per edge block, one sweep over the edge stream, no
+  one-hot ever materialized. This is the kernel the chip path compiles.
+
+Mosaic compiles the one-hot kernel for sum/mean only: its min/max
+masked scatter needs an (NB, EB, 1) mask reshape that Mosaic refuses, so
+a compiled (non-interpret) one-hot min/max raises before lowering.
 """
 from __future__ import annotations
 
@@ -61,7 +64,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.aggregations import resolve_interpret
+from repro.kernels.tpu import (LANES, compiler_params, edge_tile,
+                               pad_lanes)
+
 AGGS = ("sum", "mean", "min", "max")
+# what Mosaic compiles of the legacy one-hot kernel (module docstring)
+ONEHOT_COMPILED_AGGS = ("sum", "mean")
 
 
 def _fused_kernel(x_ref, src_ref, dst_ref, scale_ref, out_ref, cnt_ref, *,
@@ -129,7 +138,7 @@ def fused_gather_aggregate_pallas(x, src, dst, num_segments: int, *,
                                   scale=None, agg: str = "sum",
                                   edge_block: int = 128,
                                   node_block: int = 128,
-                                  interpret: bool = True):
+                                  interpret: bool | None = None):
     """x: (N, F) node features in fp32, bf16, or int8 (the table streams
     and stays VMEM-resident at its storage width; accumulation is fp32);
     src/dst: (E,) int32 endpoint id streams of the packed COO edge
@@ -140,6 +149,11 @@ def fused_gather_aggregate_pallas(x, src, dst, num_segments: int, *,
     tensor is never materialized.
     """
     assert agg in AGGS, agg
+    interpret = resolve_interpret(interpret)
+    if not interpret and agg not in ONEHOT_COMPILED_AGGS:
+        raise NotImplementedError(
+            f"the one-hot gather does not compile {agg!r} under Mosaic; "
+            "use gather_mode='dma'")
     n_src, f = x.shape
     e = src.shape[0]
     if e == 0 or num_segments == 0:
@@ -177,6 +191,7 @@ def fused_gather_aggregate_pallas(x, src, dst, num_segments: int, *,
         out_shape=jax.ShapeDtypeStruct((num_segments + n_pad, f),
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((nb, 1), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(x, src.reshape(1, e + e_pad),
       dst.reshape(1, e + e_pad), scale.reshape(1, e + e_pad))
@@ -184,22 +199,17 @@ def fused_gather_aggregate_pallas(x, src, dst, num_segments: int, *,
 
 
 # ------------------------------------------------------------ gather v2 --
-def _v2_kernel(src_ref, dst_ref, x_ref, scale_hbm, out_ref, sbuf, sems,
-               cnt_ref, *, agg: str, edge_steps: int, eb: int,
-               track_count: bool):
+def _v2_kernel(src_ref, dst_ref, scale_ref, x_ref, out_ref, cnt_ref,
+               xf_ref, *, agg: str, edge_steps: int, eb: int,
+               track_count: bool, upcast: bool):
     """One grid step folds one edge block into the resident accumulator.
 
-    src_ref/dst_ref are the *whole* id streams in SMEM (scalar prefetch);
-    scale_hbm stays in HBM (memory_space=ANY) and is copied in one edge
-    block ahead of compute through the two-slot ``sbuf`` VMEM scratch —
-    the double-buffered HBM->VMEM edge pipeline. x_ref and out_ref are
-    whole-table VMEM residents."""
+    src_ref/dst_ref/scale_ref are the *whole* per-edge streams in SMEM
+    (scalar prefetch), read one scalar per edge. x_ref and out_ref are
+    whole-table VMEM residents; a bf16/int8 table is widened once into
+    the fp32 ``xf_ref`` shadow at step 0, because Mosaic cannot slice
+    single rows out of a packed (sub-32-bit) tile."""
     j = pl.program_id(0)
-
-    def dma(slot, step):
-        return pltpu.make_async_copy(
-            scale_hbm.at[:, pl.ds(step * eb, eb)], sbuf.at[slot],
-            sems.at[slot])
 
     @pl.when(j == 0)
     def _init():
@@ -211,16 +221,10 @@ def _v2_kernel(src_ref, dst_ref, x_ref, scale_hbm, out_ref, sbuf, sems,
             out_ref[...] = jnp.full(out_ref.shape, jnp.inf, out_ref.dtype)
         else:
             out_ref[...] = jnp.full(out_ref.shape, -jnp.inf, out_ref.dtype)
-        dma(0, 0).start()
+        if upcast:
+            xf_ref[...] = x_ref[...].astype(jnp.float32)
 
-    slot = jax.lax.rem(j, 2)
-
-    @pl.when(j + 1 < edge_steps)
-    def _prefetch_next():
-        dma(1 - slot, j + 1).start()
-
-    dma(slot, j).wait()
-
+    table = xf_ref if upcast else x_ref
     base = j * eb
 
     def body(e, _):
@@ -228,8 +232,8 @@ def _v2_kernel(src_ref, dst_ref, x_ref, scale_hbm, out_ref, sbuf, sems,
         d = dst_ref[base + e]
         sl = jnp.maximum(s, 0)
         dl = jnp.maximum(d, 0)
-        sc = sbuf[slot, 0, e]
-        row = x_ref[pl.ds(sl, 1), :].astype(jnp.float32) * sc
+        sc = scale_ref[base + e]
+        row = table[pl.ds(sl, 1), :].astype(jnp.float32) * sc
         cur = out_ref[pl.ds(dl, 1), :]
         if agg in ("sum", "mean"):
             # padding edges carry scale == 0: they add a zero row at the
@@ -260,7 +264,7 @@ def fused_gather_aggregate_v2_pallas(x, src, dst, num_segments: int, *,
                                      scale=None, agg: str = "sum",
                                      edge_block: int = 128,
                                      node_block: int = 128,
-                                     interpret: bool = True):
+                                     interpret: bool | None = None):
     """One-hot-free fused gather (``gather_mode="dma"``, the default).
 
     Same contract as ``fused_gather_aggregate_pallas`` — x: (N, F) node
@@ -270,14 +274,16 @@ def fused_gather_aggregate_v2_pallas(x, src, dst, num_segments: int, *,
     returns (num_segments, F) float32, empty segments zero-fill — but a
     different machine: the id streams ride in SMEM via scalar prefetch,
     each source row is gathered by dynamic slice (O(EB * F) per edge
-    block instead of the one-hot's O(N * EB * F)), the scale stream is
-    double-buffered HBM->VMEM by explicit async copies, and the whole
+    block instead of the one-hot's O(N * EB * F)), the per-edge scale
+    rides SMEM beside them (one scalar read per edge), and the whole
     (num_segments, F) accumulator is VMEM-resident, so the edge stream
     is swept exactly once (``node_block`` is accepted for knob
-    compatibility and ignored).
+    compatibility and ignored). F is zero-padded to a multiple of 128
+    lanes inside and sliced back on the way out.
 
-    Grid: (edge_tiles,). Scratch: two-slot (2, 1, EB) scale buffer + a
-    DMA semaphore pair + the mean path's (num_segments, 1) count column.
+    Grid: (edge_tiles,). Scalar prefetch: src, dst and scale streams.
+    Scratch: the mean path's (num_segments, 1) count column + the fp32
+    shadow of a bf16/int8 table.
     """
     assert agg in AGGS, agg
     del node_block                       # v2 keeps the whole table
@@ -293,35 +299,35 @@ def fused_gather_aggregate_v2_pallas(x, src, dst, num_segments: int, *,
     if scale is None:
         scale = jnp.ones((e,), jnp.float32)
     scale = jnp.where(bad, 0.0, scale.astype(jnp.float32))
-    eb = min(edge_block, e)
+    eb = edge_tile(e, edge_block)
     e_pad = (-e) % eb
     if e_pad:
         src = jnp.pad(src, (0, e_pad), constant_values=-1)
         dst = jnp.pad(dst, (0, e_pad), constant_values=-1)
         scale = jnp.pad(scale, (0, e_pad))
+    x = pad_lanes(x)
+    fp = x.shape[1]
     steps = (e + e_pad) // eb
     track_count = agg == "mean"
+    upcast = x.dtype != jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(steps,),
-        in_specs=[
-            pl.BlockSpec((n_src, f), lambda j, s_r, d_r: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),      # scale stays HBM
-        ],
-        out_specs=pl.BlockSpec((num_segments, f),
-                               lambda j, s_r, d_r: (0, 0)),
+        in_specs=[pl.BlockSpec((n_src, fp), lambda j, *_: (0, 0))],
+        out_specs=pl.BlockSpec((num_segments, fp), lambda j, *_: (0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, 1, eb), jnp.float32),       # two-slot buffer
-            pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((num_segments if track_count else 8, 1),
+                       jnp.float32),
+            pltpu.VMEM((n_src, fp) if upcast else (8, LANES),
                        jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_v2_kernel, agg=agg, edge_steps=steps, eb=eb,
-                          track_count=track_count),
+                          track_count=track_count, upcast=upcast),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_segments, f), jnp.float32),
-        interpret=interpret,
-    )(src, dst, x, scale.reshape(1, e + e_pad))
-    return out
+        out_shape=jax.ShapeDtypeStruct((num_segments, fp), jnp.float32),
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
+    )(src, dst, scale, x)
+    return out[:, :f]

@@ -20,7 +20,8 @@ GATHER_MODES = ("onehot", "dma")
 def fused_gather_aggregate(x, src, dst, valid=None, scale=None, *,
                            num_segments: int, agg: str = "sum",
                            edge_block: int = 128, node_block: int = 128,
-                           use_pallas: bool = True, interpret: bool = True,
+                           use_pallas: bool = True,
+                           interpret: bool | None = None,
                            gather_mode: str = "dma"):
     """Gather source-node rows and aggregate them per destination segment
     in one fused pass — the (E, F) message tensor never reaches HBM.

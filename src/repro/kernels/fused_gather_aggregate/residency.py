@@ -15,8 +15,6 @@ each layer sweeps the whole edge stream before the next layer's grid
 steps begin. Blocks:
   x0     (N, Fmax)      — initial node table, read once (copy-in at
                           step (0, 0))
-  scale  (1, EB)        — per-edge phi for this step (GCN norm / SAGE
-                          validity; 0 on padding)
   sv     (N, 1)         — GCN self-loop scale (unused for SAGE)
   mask   (N, 1)         — node validity column
   w_a/w_n/w_skip (1, Fmax, Fmax), b (1, 1, Fmax) — layer i's stacked
@@ -24,8 +22,10 @@ steps begin. Blocks:
                           dims match, the projection when they differ,
                           zeros when skips are off); Pallas streams the
                           per-layer blocks double-buffered
-  qp     (1, 128)       — layer i's precision row [mode, s, lo, hi, ...]
   out    (N, Fmax)      — the resident table, revisited by every step
+Scalar prefetch (SMEM): the src/dst id streams, the per-edge phi
+(GCN norm / SAGE validity; 0 on padding), and the flattened (K, 4)
+per-layer precision rows [mode, s, lo, hi].
 Scratch: aggr (N, Fmax) accumulator, count column (mean only), and the
 quantized-table shadow xq (non-fp32 policies only).
 
@@ -54,6 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.aggregations import resolve_interpret
+from repro.kernels.tpu import compiler_params, edge_tile
 from repro.nn.layers import act
 
 RESIDENT_KINDS = ("gcn", "sage")
@@ -61,10 +63,12 @@ RESIDENT_AGGS = {"gcn": "sum", "sage": "mean"}
 
 # qp-row precision modes (matching quantization.PRECISIONS order)
 _MODE_FP32, _MODE_BF16, _MODE_INT8 = 0.0, 1.0, 2.0
+QP_WIDTH = 4
 
 
 def _cast_dyn(x, qp):
-    """Dynamic ``LayerPrecision.cast_activation``: qp = [mode, s, lo, hi].
+    """Dynamic ``LayerPrecision.cast_activation``: qp = (mode, s, lo, hi)
+    scalars.
     All three candidates are cheap VPU expressions; ``where`` selects the
     layer's mode at run time so one kernel serves mixed-precision
     stacks."""
@@ -84,14 +88,15 @@ def _round_in(x, qp):
     return jnp.where(qp[0] == _MODE_BF16, bf, x)
 
 
-def _stack_kernel(src_ref, dst_ref, x0_ref, scale_ref, sv_ref, mask_ref,
-                  wa_ref, wn_ref, wsk_ref, b_ref, qp_ref, xout_ref,
+def _stack_kernel(src_ref, dst_ref, scale_ref, qp_ref, x0_ref, sv_ref,
+                  mask_ref, wa_ref, wn_ref, wsk_ref, b_ref, xout_ref,
                   aggr_ref, cnt_ref, xq_ref, *, kind: str,
                   activation: str, edge_steps: int, eb: int,
                   has_skip: bool, quantized: bool):
     i = pl.program_id(0)
     j = pl.program_id(1)
     track_count = RESIDENT_AGGS[kind] == "mean"
+    qp = tuple(qp_ref[i * QP_WIDTH + c] for c in range(QP_WIDTH))
 
     @pl.when((i == 0) & (j == 0))
     def _copy_in():
@@ -103,7 +108,7 @@ def _stack_kernel(src_ref, dst_ref, x0_ref, scale_ref, sv_ref, mask_ref,
         if track_count:
             cnt_ref[...] = jnp.zeros_like(cnt_ref)
         if quantized:
-            xq_ref[...] = _cast_dyn(xout_ref[...], qp_ref[0])
+            xq_ref[...] = _cast_dyn(xout_ref[...], qp)
 
     table_ref = xq_ref if quantized else xout_ref
     base = j * eb
@@ -113,7 +118,7 @@ def _stack_kernel(src_ref, dst_ref, x0_ref, scale_ref, sv_ref, mask_ref,
         d = dst_ref[base + e]
         sl = jnp.maximum(s, 0)
         dl = jnp.maximum(d, 0)
-        sc = scale_ref[0, e]
+        sc = scale_ref[base + e]
         row = table_ref[pl.ds(sl, 1), :] * sc
         aggr_ref[pl.ds(dl, 1), :] += row     # padding: scale == 0
         if track_count:
@@ -125,7 +130,6 @@ def _stack_kernel(src_ref, dst_ref, x0_ref, scale_ref, sv_ref, mask_ref,
 
     @pl.when(j == edge_steps - 1)
     def _layer_boundary():
-        qp = qp_ref[0]
         aggr = aggr_ref[...]
         if track_count:
             aggr = aggr / jnp.maximum(cnt_ref[...], 1.0)
@@ -152,16 +156,18 @@ def fused_layer_stack_pallas(x, src, dst, scale, self_vec, node_mask,
                              w_a, w_n, w_skip, b, qp, *, kind: str,
                              activation: str = "relu",
                              edge_block: int = 128,
-                             interpret: bool = True,
+                             interpret: bool | None = None,
                              has_skip: bool = True,
                              quantized: bool = False):
     """Run ``K = w_n.shape[0]`` consecutive conv layers with the node
     table VMEM-resident. x: (N, Fmax) fp32 zero-padded table; src/dst:
     (E,) int32 (-1 / out-of-range = padding); scale: (E,) per-edge phi;
     self_vec / node_mask: (N, 1) fp32; w_a/w_n/w_skip: (K, Fmax, Fmax)
-    zero-padded stacks, b: (K, Fmax); qp: (K, >=4) per-layer precision
+    zero-padded stacks, b: (K, Fmax); qp: (K, 4) per-layer precision
     rows [mode, s, lo, hi]. Returns the (N, Fmax) fp32 table after the
-    last layer (callers slice to the final out_dim)."""
+    last layer (callers slice to the final out_dim). Scoped VMEM is
+    ``kernels.tpu.VMEM_LIMIT_BYTES``, the budget
+    ``core.convs.residency_plan`` checks the working set against."""
     if kind not in RESIDENT_KINDS:
         raise ValueError(f"resident stack supports {RESIDENT_KINDS}, "
                          f"got {kind!r}")
@@ -174,30 +180,26 @@ def fused_layer_stack_pallas(x, src, dst, scale, self_vec, node_mask,
     src = jnp.where(bad, -1, src)
     dst = jnp.where(bad, -1, dst)
     scale = jnp.where(bad, 0.0, scale.astype(jnp.float32))
-    eb = min(edge_block, max(e, 1))
+    eb = edge_tile(e, edge_block)
     e_pad = (-e) % eb if e else eb
     if e_pad:
         src = jnp.pad(src, (0, e_pad), constant_values=-1)
         dst = jnp.pad(dst, (0, e_pad), constant_values=-1)
         scale = jnp.pad(scale, (0, e_pad))
     steps = (e + e_pad) // eb
-    qp_pad = jnp.zeros((k, 128), jnp.float32).at[:, :qp.shape[1]].set(
-        qp.astype(jnp.float32))
+    qp = jnp.asarray(qp, jnp.float32)[:, :QP_WIDTH].reshape(-1)
+    layer_block = pl.BlockSpec((1, fmax, fmax), lambda i, j, *_: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(k, steps),
         in_specs=[
-            pl.BlockSpec((n, fmax), lambda i, j, s_r, d_r: (0, 0)),
-            pl.BlockSpec((1, eb), lambda i, j, s_r, d_r: (0, j)),
-            pl.BlockSpec((n, 1), lambda i, j, s_r, d_r: (0, 0)),
-            pl.BlockSpec((n, 1), lambda i, j, s_r, d_r: (0, 0)),
-            pl.BlockSpec((1, fmax, fmax), lambda i, j, s_r, d_r: (i, 0, 0)),
-            pl.BlockSpec((1, fmax, fmax), lambda i, j, s_r, d_r: (i, 0, 0)),
-            pl.BlockSpec((1, fmax, fmax), lambda i, j, s_r, d_r: (i, 0, 0)),
-            pl.BlockSpec((1, fmax), lambda i, j, s_r, d_r: (i, 0)),
-            pl.BlockSpec((1, 128), lambda i, j, s_r, d_r: (i, 0)),
+            pl.BlockSpec((n, fmax), lambda i, j, *_: (0, 0)),
+            pl.BlockSpec((n, 1), lambda i, j, *_: (0, 0)),
+            pl.BlockSpec((n, 1), lambda i, j, *_: (0, 0)),
+            layer_block, layer_block, layer_block,
+            pl.BlockSpec((1, 1, fmax), lambda i, j, *_: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((n, fmax), lambda i, j, s_r, d_r: (0, 0)),
+        out_specs=pl.BlockSpec((n, fmax), lambda i, j, *_: (0, 0)),
         scratch_shapes=[
             pltpu.VMEM((n, fmax), jnp.float32),
             pltpu.VMEM((n if RESIDENT_AGGS[kind] == "mean" else 8, 1),
@@ -211,10 +213,10 @@ def fused_layer_stack_pallas(x, src, dst, scale, self_vec, node_mask,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, fmax), jnp.float32),
-        interpret=interpret,
-    )(src, dst, x.astype(jnp.float32),
-      scale.reshape(1, e + e_pad),
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
+    )(src, dst, scale, qp, x.astype(jnp.float32),
       self_vec.astype(jnp.float32).reshape(n, 1),
       node_mask.astype(jnp.float32).reshape(n, 1),
       w_a.astype(jnp.float32), w_n.astype(jnp.float32),
-      w_skip.astype(jnp.float32), b.astype(jnp.float32), qp_pad)
+      w_skip.astype(jnp.float32), b.astype(jnp.float32).reshape(k, 1, fmax))

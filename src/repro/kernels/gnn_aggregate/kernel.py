@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.aggregations import resolve_interpret
+
 AGGS = ("sum", "mean", "min", "max", "var", "std")
 
 
@@ -88,7 +90,7 @@ def _agg_kernel(x_ref, nbr_ref, out_ref, *, agg: str, k_max: int):
 
 
 def gnn_aggregate_pallas(x, nbr, *, agg: str = "sum", block_nodes: int = 128,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """x: (N, F) fp32 node table; nbr: (N, K) int32 neighbor table
     (-1 padded). Returns (N, F) aggregated neighbor features."""
     assert agg in AGGS, agg
@@ -108,6 +110,6 @@ def gnn_aggregate_pallas(x, nbr, *, agg: str = "sum", block_nodes: int = 128,
         ],
         out_specs=pl.BlockSpec((bn, f), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, f), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, nbr)
     return out[:n]

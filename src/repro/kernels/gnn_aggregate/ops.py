@@ -12,7 +12,7 @@ from repro.kernels.gnn_aggregate.ref import gnn_aggregate_ref
 @partial(jax.jit, static_argnames=("agg", "block_nodes", "use_pallas",
                                    "interpret"))
 def gnn_aggregate(x, nbr, *, agg: str = "sum", block_nodes: int = 128,
-                  use_pallas: bool = True, interpret: bool = True):
+                  use_pallas: bool = True, interpret: bool | None = None):
     """Aggregate neighbor embeddings. x (N,F); nbr (N,K) int32 -1-padded.
 
     use_pallas=False falls back to the XLA reference (the path used under

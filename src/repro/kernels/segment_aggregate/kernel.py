@@ -50,7 +50,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.aggregations import resolve_interpret
+from repro.kernels.tpu import compiler_params, edge_tile, pad_lanes
+
 AGGS = ("sum", "mean", "min", "max", "var", "std")
+# what Mosaic compiles of the legacy one-hot kernel: min/max need an
+# (NB, EB, 1) mask reshape and var/std a dynamic_slice, both refused
+ONEHOT_COMPILED_AGGS = ("sum", "mean")
 
 
 def _seg_kernel(msg_ref, dst_ref, out_ref, *scratch, agg: str,
@@ -134,15 +140,21 @@ def _seg_kernel(msg_ref, dst_ref, out_ref, *scratch, agg: str,
 def segment_aggregate_pallas(messages, seg_ids, num_segments: int, *,
                              agg: str = "sum", edge_block: int = 128,
                              node_block: int = 128,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """messages: (E, F) in fp32, bf16, or int8 — tiles stream at the
     storage width, accumulation is fp32; seg_ids: (E,) int32 destination
     segment per edge, -1 (or any id outside [0, num_segments)) on
     padding. Returns (num_segments, F) float32 aggregates; empty
     segments zero-fill (the var/std clamp floor counts as zero at fp32
-    tolerance).
+    tolerance). Compiled (non-interpret) only for
+    ``ONEHOT_COMPILED_AGGS``; the chip path is ``gather_mode="dma"``.
     """
     assert agg in AGGS, agg
+    interpret = resolve_interpret(interpret)
+    if not interpret and agg not in ONEHOT_COMPILED_AGGS:
+        raise NotImplementedError(
+            f"the one-hot segment kernel does not compile {agg!r} under "
+            "Mosaic; use gather_mode='dma'")
     e, f = messages.shape
     eb = min(edge_block, e)
     nb = min(node_block, num_segments)
@@ -173,6 +185,7 @@ def segment_aggregate_pallas(messages, seg_ids, num_segments: int, *,
         out_shape=jax.ShapeDtypeStruct((num_segments + n_pad, f),
                                        jnp.float32),
         scratch_shapes=scratch,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(messages, dst)
     return out[:num_segments]
@@ -180,22 +193,25 @@ def segment_aggregate_pallas(messages, seg_ids, num_segments: int, *,
 
 # ----------------------------------------------------------- segment v2 --
 def _seg_v2_kernel(dst_ref, msg_hbm, out_ref, sbuf, sems, cnt_ref,
-                   mean_ref, m2_ref, *, agg: str, edge_steps: int,
-                   eb: int):
+                   mean_ref, m2_ref, tile_ref, *, agg: str,
+                   edge_steps: int, eb: int, upcast: bool):
     """One grid step folds one message tile into the resident table.
 
     dst_ref is the *whole* id stream in SMEM (scalar prefetch); msg_hbm
     stays in HBM (memory_space=ANY) and is copied one edge block ahead
-    of compute through the two-slot region of ``sbuf`` (a (2*EB, F)
-    VMEM scratch at the message storage width) — the double-buffered
-    HBM->VMEM edge pipeline. out_ref and the Welford mean/M2 scratch are
-    whole-table VMEM residents, so the edge stream is swept once."""
+    of compute through the two slots of ``sbuf`` (a (2, EB, F) VMEM
+    scratch at the message storage width) — the double-buffered
+    HBM->VMEM edge pipeline. A bf16/int8 tile is widened once into the
+    fp32 ``tile_ref`` before the per-edge loop, because Mosaic cannot
+    slice single rows out of a packed (sub-32-bit) tile. out_ref and
+    the Welford mean/M2 scratch are whole-table VMEM residents, so the
+    edge stream is swept once."""
     j = pl.program_id(0)
 
     def dma(slot, step):
         return pltpu.make_async_copy(
-            msg_hbm.at[pl.ds(step * eb, eb), :],
-            sbuf.at[pl.ds(slot * eb, eb), :], sems.at[slot])
+            msg_hbm.at[pl.ds(step * eb, eb), :], sbuf.at[slot],
+            sems.at[slot])
 
     @pl.when(j == 0)
     def _init():
@@ -220,13 +236,18 @@ def _seg_v2_kernel(dst_ref, msg_hbm, out_ref, sbuf, sems, cnt_ref,
 
     dma(slot, j).wait()
 
+    if upcast:
+        tile_ref[...] = sbuf[slot].astype(jnp.float32)
     base = j * eb
 
     def body(e, _):
         d = dst_ref[base + e]
         dl = jnp.maximum(d, 0)
         ok = d >= 0
-        row = sbuf[pl.ds(slot * eb + e, 1), :].astype(jnp.float32)
+        if upcast:
+            row = tile_ref[pl.ds(e, 1), :]
+        else:
+            row = sbuf[slot, pl.ds(e, 1), :]
         if agg in ("sum", "mean"):
             cur = out_ref[pl.ds(dl, 1), :]
             out_ref[pl.ds(dl, 1), :] = \
@@ -271,7 +292,7 @@ def _seg_v2_kernel(dst_ref, msg_hbm, out_ref, sbuf, sems, cnt_ref,
 def segment_aggregate_v2_pallas(messages, seg_ids, num_segments: int, *,
                                 agg: str = "sum", edge_block: int = 128,
                                 node_block: int = 128,
-                                interpret: bool = True):
+                                interpret: bool | None = None):
     """One-hot-free segment aggregation (``gather_mode="dma"``, the
     default) — same contract as ``segment_aggregate_pallas`` (messages
     (E, F) at fp32/bf16/int8 storage width, fp32 accumulation, seg_ids
@@ -281,11 +302,13 @@ def segment_aggregate_v2_pallas(messages, seg_ids, num_segments: int, *,
     HBM->VMEM by explicit async copies, and the whole accumulator table
     (plus the Welford mean/M2 pair for var/std) is VMEM-resident, so the
     edge stream is swept exactly once (``node_block`` is accepted for
-    knob compatibility and ignored).
+    knob compatibility and ignored). F is zero-padded to a multiple of
+    128 lanes inside and sliced back on the way out.
 
-    Grid: (edge_tiles,). Scratch: two-slot (2*EB, F) message buffer at
+    Grid: (edge_tiles,). Scratch: two-slot (2, EB, F) message buffer at
     storage width + a DMA semaphore pair + the (num_segments, 1) count
-    column + (num_segments, F) Welford mean/M2 for var/std.
+    column + (num_segments, F) Welford mean/M2 for var/std + the fp32
+    (EB, F) widened tile for bf16/int8 messages.
     """
     assert agg in AGGS, agg
     del node_block                       # v2 keeps the whole table
@@ -295,36 +318,40 @@ def segment_aggregate_v2_pallas(messages, seg_ids, num_segments: int, *,
     seg_ids = seg_ids.astype(jnp.int32)
     seg_ids = jnp.where((seg_ids >= 0) & (seg_ids < num_segments),
                         seg_ids, -1)
-    eb = min(edge_block, e)
+    eb = edge_tile(e, edge_block, messages.dtype)
     e_pad = (-e) % eb
     if e_pad:
         messages = jnp.pad(messages, ((0, e_pad), (0, 0)))
         seg_ids = jnp.pad(seg_ids, (0, e_pad), constant_values=-1)
+    messages = pad_lanes(messages)
+    fp = messages.shape[1]
     steps = (e + e_pad) // eb
     welford = agg in ("var", "std")
     track_count = agg != "sum"
+    upcast = messages.dtype != jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(steps,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),     # messages stay HBM
+            pl.BlockSpec(memory_space=pl.ANY),        # messages stay HBM
         ],
-        out_specs=pl.BlockSpec((num_segments, f),
-                               lambda j, d_r: (0, 0)),
+        out_specs=pl.BlockSpec((num_segments, fp), lambda j, d_r: (0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2 * eb, f), messages.dtype),  # two-slot buffer
+            pltpu.VMEM((2, eb, fp), messages.dtype),  # two-slot buffer
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((num_segments if track_count else 8, 1),
                        jnp.float32),
-            pltpu.VMEM((num_segments if welford else 8, f), jnp.float32),
-            pltpu.VMEM((num_segments if welford else 8, f), jnp.float32),
+            pltpu.VMEM((num_segments if welford else 8, fp), jnp.float32),
+            pltpu.VMEM((num_segments if welford else 8, fp), jnp.float32),
+            pltpu.VMEM((eb if upcast else 8, fp), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_seg_v2_kernel, agg=agg, edge_steps=steps,
-                          eb=eb),
+                          eb=eb, upcast=upcast),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_segments, f), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((num_segments, fp), jnp.float32),
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(seg_ids, messages)
-    return out
+    return out[:, :f]

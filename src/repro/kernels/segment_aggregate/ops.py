@@ -19,7 +19,8 @@ GATHER_MODES = ("onehot", "dma")
 def segment_aggregate(messages, seg_ids, valid=None, *, num_segments: int,
                       agg: str = "sum", edge_block: int = 128,
                       node_block: int = 128, use_pallas: bool = True,
-                      interpret: bool = True, gather_mode: str = "dma"):
+                      interpret: bool | None = None,
+                      gather_mode: str = "dma"):
     """Aggregate packed COO edge messages per destination segment.
 
     messages (E, F) — fp32, bf16, or int8; tiles stream at the storage

@@ -32,37 +32,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.aggregations import resolve_interpret
+from repro.kernels.tpu import compiler_params, edge_tile
+
 NEG_INF = -1e30     # finite "empty" max: keeps -inf logits NaN-free
 TINY = 1e-30        # denominator floor (empty segments divide by this)
 
 
-def _softmax_stats_kernel(seg_ref, logit_hbm, m_ref, l_ref, sbuf, sems, *,
-                          edge_steps: int, eb: int):
-    """One grid step folds one logit tile into the resident (m, l)
-    tables. Same machine as ``segment_aggregate._seg_v2_kernel``: the
-    whole seg-id stream rides SMEM via scalar prefetch, logit tiles are
-    double-buffered HBM->VMEM, and both per-segment tables stay
-    VMEM-resident across the single edge sweep."""
+def _softmax_stats_kernel(seg_ref, logit_ref, m_ref, l_ref, *, eb: int):
+    """One grid step folds one edge block into the resident (m, l)
+    tables. The seg-id and logit streams both ride SMEM via scalar
+    prefetch (one scalar read each per edge), and both per-segment
+    tables stay VMEM-resident across the single edge sweep."""
     j = pl.program_id(0)
-
-    def dma(slot, step):
-        return pltpu.make_async_copy(
-            logit_hbm.at[pl.ds(step * eb, eb), :],
-            sbuf.at[pl.ds(slot * eb, eb), :], sems.at[slot])
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
         l_ref[...] = jnp.zeros_like(l_ref)
-        dma(0, 0).start()
-
-    slot = jax.lax.rem(j, 2)
-
-    @pl.when(j + 1 < edge_steps)
-    def _prefetch_next():
-        dma(1 - slot, j + 1).start()
-
-    dma(slot, j).wait()
 
     base = j * eb
 
@@ -70,7 +57,7 @@ def _softmax_stats_kernel(seg_ref, logit_hbm, m_ref, l_ref, sbuf, sems, *,
         d = seg_ref[base + e]
         dl = jnp.maximum(d, 0)
         ok = d >= 0
-        z = sbuf[pl.ds(slot * eb + e, 1), :].astype(jnp.float32)
+        z = logit_ref[base + e]
         m_prev = m_ref[pl.ds(dl, 1), :]
         # the running max never drops below NEG_INF, so a -inf logit
         # leaves it unchanged and exp(m_prev - m_new) stays exp(0) = 1
@@ -87,14 +74,14 @@ def _softmax_stats_kernel(seg_ref, logit_hbm, m_ref, l_ref, sbuf, sems, *,
 
 def segment_softmax_stats_pallas(logits, seg_ids, num_segments: int, *,
                                  edge_block: int = 128,
-                                 interpret: bool = True):
+                                 interpret: bool | None = None):
     """Per-segment online-softmax statistics over a packed edge stream.
 
     logits: (E,) float; seg_ids: (E,) int32 with -1 / out-of-range =
     padding. Returns ``(m, l)``: (num_segments,) float32 running max
     (NEG_INF for empty segments) and exp-sum denominator (0 for empty
-    segments). Grid: (edge_tiles,); scratch: two-slot (2*EB, 1) logit
-    buffer + a DMA semaphore pair; both output tables VMEM-resident."""
+    segments). Grid: (edge_tiles,); scalar prefetch: the seg-id and
+    logit streams; both output tables VMEM-resident."""
     e = logits.shape[0]
     if e == 0 or num_segments == 0:
         return (jnp.full((num_segments,), NEG_INF, jnp.float32),
@@ -102,43 +89,38 @@ def segment_softmax_stats_pallas(logits, seg_ids, num_segments: int, *,
     seg_ids = seg_ids.astype(jnp.int32)
     seg_ids = jnp.where((seg_ids >= 0) & (seg_ids < num_segments),
                         seg_ids, -1)
-    z = logits.astype(jnp.float32).reshape(e, 1)
-    eb = min(edge_block, e)
+    z = logits.astype(jnp.float32)
+    eb = edge_tile(e, edge_block)
     e_pad = (-e) % eb
     if e_pad:
-        z = jnp.pad(z, ((0, e_pad), (0, 0)))
+        z = jnp.pad(z, (0, e_pad))
         seg_ids = jnp.pad(seg_ids, (0, e_pad), constant_values=-1)
     steps = (e + e_pad) // eb
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(steps,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),      # logits stay HBM
-        ],
+        in_specs=[],
         out_specs=[
-            pl.BlockSpec((num_segments, 1), lambda j, s_r: (0, 0)),
-            pl.BlockSpec((num_segments, 1), lambda j, s_r: (0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2 * eb, 1), jnp.float32),      # two-slot buffer
-            pltpu.SemaphoreType.DMA((2,)),
+            pl.BlockSpec((num_segments, 1), lambda j, *_: (0, 0)),
+            pl.BlockSpec((num_segments, 1), lambda j, *_: (0, 0)),
         ],
     )
     m, l = pl.pallas_call(
-        functools.partial(_softmax_stats_kernel, edge_steps=steps, eb=eb),
+        functools.partial(_softmax_stats_kernel, eb=eb),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((num_segments, 1), jnp.float32),
             jax.ShapeDtypeStruct((num_segments, 1), jnp.float32),
         ],
-        interpret=interpret,
+        compiler_params=compiler_params(),
+        interpret=resolve_interpret(interpret),
     )(seg_ids, z)
     return m[:, 0], l[:, 0]
 
 
 def segment_softmax_pallas(logits, seg_ids, num_segments: int, *,
                            edge_block: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """Per-edge softmax weights normalized within each segment.
 
     logits: (E,); seg_ids: (E,) with -1 / out-of-range = padding.

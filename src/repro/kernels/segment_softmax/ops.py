@@ -14,7 +14,7 @@ from repro.kernels.segment_softmax.ref import segment_softmax_ref
                                    "use_pallas", "interpret"))
 def segment_softmax(logits, seg_ids, valid=None, *, num_segments: int,
                     edge_block: int = 128, use_pallas: bool = True,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Normalize packed per-edge logits within each destination segment.
 
     logits (E,) float — any magnitude; the online-softmax state machine

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.aggregations import resolve_interpret
+
 
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
     @pl.when(pl.program_id(2) == 0)
@@ -33,7 +35,7 @@ def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
 
 
 def tiled_matmul_pallas(x, w, *, block_m: int = 128, block_n: int = 128,
-                        block_k: int = 128, interpret: bool = True):
+                        block_k: int = 128, interpret: bool | None = None):
     """x: (M, K) @ w: (K, N) -> (M, N), fp32 accumulation in VMEM."""
     m, k = x.shape
     k2, n = w.shape
@@ -56,6 +58,6 @@ def tiled_matmul_pallas(x, w, *, block_m: int = 128, block_n: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk_: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)
     return out[:m, :n]

@@ -25,7 +25,7 @@ def blocks_from_parallelism(p_in: int, p_out: int) -> tuple:
                                    "use_pallas", "interpret"))
 def tiled_matmul(x, w, *, block_m: int = 128, block_n: int = 128,
                  block_k: int = 128, use_pallas: bool = True,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     if use_pallas:
         return tiled_matmul_pallas(x, w, block_m=block_m, block_n=block_n,
                                    block_k=block_k, interpret=interpret)

@@ -35,6 +35,11 @@ budget-full; measured service times make the reported p50/p99
 traffic-shaped while the compute is real. Full lifecycle:
 docs/SERVING.md.
 
+``--gnn`` serves ``configs.gnn.benchmark_config`` (the paper's widths)
+unless ``--reduced`` asks for the toy config. A request that ends
+``failed`` makes the process exit non-zero with the first executor
+error; serving injects no faults, so every failure is real.
+
   PYTHONPATH=src python -m repro.launch.serve --gnn --conv gcn \
       --requests 256 --batch-graphs 32 [--agg-backend pallas] \
       [--dataflow auto|aggregate_first|transform_first] \
@@ -53,7 +58,7 @@ import numpy as np
 
 from repro.configs.registry import ARCHS, get_config
 from repro.core import convs as Cv
-from repro.launch.mesh import make_host_mesh
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.nn import param as prm
 
@@ -374,8 +379,20 @@ def drain_gnn_queue_continuous(fn, params, queue, node_budget: int,
     return sched.responses, stats
 
 
-def gnn_main(args):
+def gnn_model_config(args):
+    """The model ``--gnn`` serves: the paper widths
+    (``configs.gnn.benchmark_config``) or, with ``--reduced``, the toy
+    config, under the CLI's dataflow and precision."""
     from repro.configs.gnn import DATASETS, config as gnn_config
+    cfg = gnn_config(args.conv, reduced=args.reduced)
+    return dataclasses.replace(
+        cfg, gnn_dataflow=args.dataflow,
+        avg_degree=float(DATASETS["qm9"].avg_degree),
+        gnn_precision=args.precision)
+
+
+def gnn_main(args):
+    from repro.configs.gnn import DATASETS
     from repro.core import aggregations as agg_mod
     from repro.core import gnn_model as G
     from repro.data import pipeline as P
@@ -385,11 +402,8 @@ def gnn_main(args):
     # aggregation defaults); the default stays XLA, the safe choice under
     # pjit and on CPU hosts
     agg_mod.set_default_backend(args.agg_backend)
-    cfg = gnn_config(args.conv, reduced=args.reduced)
+    cfg = gnn_model_config(args)
     ds = DATASETS["qm9"]
-    cfg = dataclasses.replace(cfg, gnn_dataflow=args.dataflow,
-                              avg_degree=float(ds.avg_degree),
-                              gnn_precision=args.precision)
     params = prm.materialize(G.model_plan(cfg), jax.random.key(0))
     queue = [P.make_graph(ds, i) for i in range(args.requests)]
     node_budget = P.size_budget(args.batch_graphs, ds.avg_nodes)
@@ -539,10 +553,14 @@ def gnn_main(args):
     return stats
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCHS), default="qwen3-8b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the toy-width config (LM: the reduced "
+                         "arch; --gnn: hidden 16 / out 8) instead of the "
+                         "full one (--gnn: configs.gnn.benchmark_config, "
+                         "the paper's §VIII-B widths)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=32)
@@ -604,10 +622,19 @@ def main():
                          "waves over a ('data',) mesh (needs >= N "
                          "devices; on CPU set XLA_FLAGS="
                          "--xla_force_host_platform_device_count)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
 
     if args.gnn:
-        gnn_main(args)
+        stats = gnn_main(args)
+        if stats.get("failed"):
+            raise SystemExit(
+                f"{stats['failed']} requests failed after retries; first "
+                f"error: {stats.get('first_error')}")
         return
 
     cfg = get_config(args.arch, reduced=args.reduced)

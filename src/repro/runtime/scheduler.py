@@ -476,6 +476,9 @@ class ContinuousScheduler:
         self.failed_launches = 0
         self.probes_succeeded = 0
         self.probes_failed = 0
+        # "Type: message" of the first exception an executor raised, so a
+        # compiler or out-of-memory error reaches summary() as text
+        self.first_error: str | None = None
         self._depth: dict = {}           # tenant -> pending count
         self._next_id = 0
         self._seq = 0
@@ -573,6 +576,7 @@ class ContinuousScheduler:
                       nodes_used=self._nodes_used)
         s["retries"] = self.retries
         s["failed_launches"] = self.failed_launches
+        s["first_error"] = self.first_error
         s["lane_states"] = [l.state for l in self.lanes]
         s["quarantined_executors"] = sorted(
             i for i, l in enumerate(self.lanes)
@@ -752,7 +756,7 @@ class ContinuousScheduler:
                     out, svc = executor.run_fallback(sel.fallback.graph)
             except Exception as e:     # noqa: BLE001 — lane fault, not ours
                 out, svc = None, 0.0
-                error, after_s = FAIL_CRASH, getattr(e, "after_s", 0.0)
+                error, after_s = self._crash(e)
         else:
             kind, reqs = "packed", sel.requests
             for r in reqs:
@@ -766,7 +770,7 @@ class ContinuousScheduler:
                 out, svc = executor.run_batch(batch)
             except Exception as e:     # noqa: BLE001 — lane fault, not ours
                 out, svc = None, 0.0
-                error, after_s = FAIL_CRASH, getattr(e, "after_s", 0.0)
+                error, after_s = self._crash(e)
             if error is None:
                 self._fills.append(len(reqs))
                 self._nodes_used += sum(r.graph.num_nodes for r in reqs)
@@ -785,6 +789,13 @@ class ContinuousScheduler:
                               "req_ids": [r.req_id for r in reqs]})
         self.inflight[exec_id] = unit
         self._seq += 1
+
+    def _crash(self, e: Exception):
+        """Record an executor exception (the first one's text is kept
+        for ``summary()``); returns the launch's (error, fail-after)."""
+        if self.first_error is None:
+            self.first_error = f"{type(e).__name__}: {e}"
+        return FAIL_CRASH, getattr(e, "after_s", 0.0)
 
     def _complete_due(self, now: float):
         while True:

@@ -183,6 +183,56 @@ def test_dead_letter_after_max_retries():
     assert sched.responses[-1].status == S.SERVED_PACKED
 
 
+class _Raising:
+    """An executor lane whose launches raise, as a chip compile or
+    out-of-memory error would."""
+    can_fallback = False
+
+    def __init__(self, *messages):
+        self.messages = list(messages)
+
+    def run_batch(self, batch):
+        raise RuntimeError(self.messages.pop(0))
+
+
+def test_first_executor_error_reaches_summary():
+    """The first exception's type and message are kept in summary(),
+    later ones do not overwrite it, and a fault-free run reports None."""
+    sched = sched_with([_Raising("Mosaic failed to compile TPU kernel",
+                                 "RESOURCE_EXHAUSTED", "third")],
+                       max_retries=2, quarantine_after=10)
+    sched.submit(P.make_graph(DS, 0))
+    sched.drain()
+    s = sched.summary()
+    assert s["failed"] == 1
+    assert s["first_error"] == \
+        "RuntimeError: Mosaic failed to compile TPU kernel"
+    ok = sched_with([lane(0.1)])
+    ok.submit(P.make_graph(DS, 0))
+    ok.drain()
+    assert ok.summary()["first_error"] is None
+
+
+def test_serve_exits_nonzero_and_prints_first_error(monkeypatch):
+    """``serve --gnn`` fails the process when any request ends
+    ``failed``, with the first executor error in the exit message."""
+    from repro.launch import serve
+
+    class Crashing(S.MeasuredExecutor):
+        def run_batch(self, batch):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(S, "MeasuredExecutor", Crashing)
+    monkeypatch.setattr(serve.compile_cache, "enable", lambda: None)
+    with pytest.raises(SystemExit) as exc:
+        serve.main(["--gnn", "--reduced", "--requests", "4",
+                    "--batch-graphs", "4", "--scheduler", "continuous",
+                    "--max-retries", "1"])
+    msg = str(exc.value.code)
+    assert "4 requests failed" in msg
+    assert "RuntimeError: Mosaic failed to compile TPU kernel" in msg
+
+
 def test_quarantine_and_probe_back_closed_form():
     """Two consecutive crashes quarantine lane 1 with probe_at exactly
     failure time + cooldown; once eligible and the healthy lane is

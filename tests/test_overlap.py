@@ -19,8 +19,7 @@ SCRIPT = textwrap.dedent("""
     # ag_matmul: Y = all_gather(X_rowsharded) @ W
     x = jnp.asarray(rng.standard_normal((32, 16)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((16, 24)), jnp.float32)
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \\
-            else mesh:
+    with jax.set_mesh(mesh):
         y = jax.jit(ag)(x, w)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w),
                                rtol=1e-4, atol=1e-4)
@@ -28,8 +27,7 @@ SCRIPT = textwrap.dedent("""
     # matmul_rs: Y = reduce_scatter(X @ W) with contraction sharded
     x2 = jnp.asarray(rng.standard_normal((32, 64)), jnp.float32)
     w2 = jnp.asarray(rng.standard_normal((64, 8)), jnp.float32)
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \\
-            else mesh:
+    with jax.set_mesh(mesh):
         y2 = jax.jit(rs)(x2, w2)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(x2 @ w2),
                                rtol=1e-4, atol=1e-4)
