@@ -206,6 +206,7 @@ def _active_precision(precision):
 
 
 # --------------------------------------------------------- segment form --
+@jax.named_scope("segment_aggregate")
 def segment_aggregate(agg: str, messages, seg_ids, num_segments: int,
                       valid=None, *, backend: str | None = None,
                       edge_block: int | None = None,
@@ -293,6 +294,7 @@ def segment_aggregate(agg: str, messages, seg_ids, num_segments: int,
     return out[:num_segments]
 
 
+@jax.named_scope("segment_softmax")
 def segment_softmax(logits, seg_ids, num_segments: int, valid=None, *,
                     backend: str | None = None,
                     edge_block: int | None = None,
@@ -344,6 +346,7 @@ def segment_softmax(logits, seg_ids, num_segments: int, valid=None, *,
 GATHER_AGGREGATIONS = ("sum", "mean", "min", "max")
 
 
+@jax.named_scope("gather_aggregate")
 def gather_aggregate(agg: str, x, src, dst, num_segments: int, valid=None,
                      scale=None, *, backend: str | None = None,
                      edge_block: int | None = None,

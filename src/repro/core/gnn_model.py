@@ -35,6 +35,7 @@ from repro.core import convs as C
 from repro.core import quantization as Q
 from repro.core.pooling import global_pooling, segment_global_pooling
 from repro.nn.layers import act, linear, linear_plan
+from repro.runtime import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,7 +177,8 @@ def graph_inputs(batch_el: dict) -> tuple:
 def packed_to_device(batch: dict) -> dict:
     """Host GraphBatch -> device arrays, stripping the host-only target
     buffer ``y`` so it is never traced into the inference program."""
-    return {k: jnp.asarray(v) for k, v in batch.items() if k != "y"}
+    with trace.span("device.put"):
+        return {k: jnp.asarray(v) for k, v in batch.items() if k != "y"}
 
 
 def packed_inputs(batch: dict) -> tuple:
@@ -236,29 +238,30 @@ def _backbone(params, cfg: GNNModelConfig, g, x, node_mask,
     """
     nl = cfg.gnn_num_layers
     for i in range(nl):
-        cc = cfg.conv_cfg(i)
-        p_i = params["convs"][f"c{i}"]
-        x_in = x
-        lp = policy.layer(i) if policy is not None else None
-        if lp is not None and lp.compute != "fp32":
-            cc = dataclasses.replace(cc, precision=lp)
-            p_i = lp.cast_params(p_i)
-            x_in = lp.cast_activation(x)
-        h = C.conv_apply(p_i, g, x_in, cc).astype(jnp.float32)
-        if record is not None:
-            record.append(jnp.maximum(jnp.max(jnp.abs(x)),
-                                      jnp.max(jnp.abs(h))))
-        if quant is not None:
-            h = Q.quantize(h, quant)
-        if cfg.gnn_skip_connection:
-            skip = x
-            if f"skip{i}" in params:
-                skip = linear(params[f"skip{i}"], x)
-            h = h + skip
-        x = act(cfg.gnn_activation)(h)
-        x = x * node_mask[:, None]
-        if quant is not None:
-            x = Q.quantize(x, quant)
+        with jax.named_scope(f"conv{i}"):
+            cc = cfg.conv_cfg(i)
+            p_i = params["convs"][f"c{i}"]
+            x_in = x
+            lp = policy.layer(i) if policy is not None else None
+            if lp is not None and lp.compute != "fp32":
+                cc = dataclasses.replace(cc, precision=lp)
+                p_i = lp.cast_params(p_i)
+                x_in = lp.cast_activation(x)
+            h = C.conv_apply(p_i, g, x_in, cc).astype(jnp.float32)
+            if record is not None:
+                record.append(jnp.maximum(jnp.max(jnp.abs(x)),
+                                          jnp.max(jnp.abs(h))))
+            if quant is not None:
+                h = Q.quantize(h, quant)
+            if cfg.gnn_skip_connection:
+                skip = x
+                if f"skip{i}" in params:
+                    skip = linear(params[f"skip{i}"], x)
+                h = h + skip
+            x = act(cfg.gnn_activation)(h)
+            x = x * node_mask[:, None]
+            if quant is not None:
+                x = Q.quantize(x, quant)
         if exchange is not None and i < nl - 1:
             x = exchange(x)
     return x
@@ -319,15 +322,17 @@ def apply_packed(params, cfg: GNNModelConfig, batch: dict,
                   exchange=halo_exchange)
     if cfg.task == "node" or return_node_features:
         return x
-    pooled = segment_global_pooling(cfg.global_pooling, x, graph_id,
-                                    num_graphs, node_mask)
-    if quant is not None:
-        pooled = Q.quantize(pooled, quant)
-    out = mlp_head_apply(params["mlp"], pooled.astype(x.dtype),
-                         cfg.mlp_head, quant,
-                         pol.head if pol is not None else None)
-    if cfg.output_activation:
-        out = act(cfg.output_activation)(out)
+    with jax.named_scope("pooling"):
+        pooled = segment_global_pooling(cfg.global_pooling, x, graph_id,
+                                        num_graphs, node_mask)
+        if quant is not None:
+            pooled = Q.quantize(pooled, quant)
+    with jax.named_scope("head"):
+        out = mlp_head_apply(params["mlp"], pooled.astype(x.dtype),
+                             cfg.mlp_head, quant,
+                             pol.head if pol is not None else None)
+        if cfg.output_activation:
+            out = act(cfg.output_activation)(out)
     return out
 
 
@@ -455,8 +460,9 @@ def stack_shards(shards) -> dict:
     like ``packed_to_device``. Accepts a ShardedBatch or a plain list of
     same-shape GraphBatch dicts."""
     shards = getattr(shards, "shards", shards)
-    return {k: jnp.stack([jnp.asarray(b[k]) for b in shards])
-            for k in shards[0] if k != "y"}
+    with trace.span("device.put"):
+        return {k: jnp.stack([jnp.asarray(b[k]) for b in shards])
+                for k in shards[0] if k != "y"}
 
 
 def make_sharded_apply(cfg: GNNModelConfig, mesh,

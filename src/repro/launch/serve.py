@@ -61,6 +61,7 @@ from repro.core import convs as Cv
 from repro.launch import compile_cache
 from repro.models import lm
 from repro.nn import param as prm
+from repro.runtime import trace
 
 
 def pad_caches(prefill_caches, full_caches):
@@ -173,9 +174,9 @@ def _launch_packed(run_batch, batches, oversize, fallback_fn, *,
     outs = []
     served = 0
     slots_used = 0
-    t0 = time.perf_counter()
-    for b in batches:
-        outs.append(run_batch(b))
+    for i, b in enumerate(batches):
+        with trace.span("device.launch", batch=i):
+            outs.append(run_batch(b))
         served += graphs_in(b)
         slots_used += slots_in(b)
     over_outs, over_status = [], []
@@ -191,8 +192,8 @@ def _launch_packed(run_batch, batches, oversize, fallback_fn, *,
             over_outs.append(None)
             over_status.append(S.REJECTED_OVERSIZE)
     live = [o for o in over_outs if o is not None]
-    jax.block_until_ready(outs + live)
-    total_s = time.perf_counter() - t0
+    with trace.span("device.wait"):
+        jax.block_until_ready(outs + live)
     n_part = over_status.count(S.SERVED_PARTITIONED)
     n_fallback = over_status.count(S.SERVED_FALLBACK)
     stats = {
@@ -201,12 +202,17 @@ def _launch_packed(run_batch, batches, oversize, fallback_fn, *,
         "partitioned_served": n_part,
         "fallback_served": n_fallback,
         "n_batches": len(batches),
-        "graphs_per_s": (served + n_part + n_fallback)
-        / max(total_s, 1e-12),
         "node_slot_utilization": slots_used / max(slot_capacity, 1),
-        "total_s": total_s,
     }
     return outs, over_outs, over_status, stats
+
+
+def _timed(stats: dict, t0: float) -> dict:
+    """A wave drain's ``total_s`` and ``graphs_per_s``, timed from the
+    drain's entry: admission and packing count."""
+    stats["total_s"] = time.perf_counter() - t0
+    stats["graphs_per_s"] = stats["served"] / max(stats["total_s"], 1e-12)
+    return stats
 
 
 def drain_gnn_queue(fn, params, queue, node_budget: int, edge_budget: int,
@@ -240,23 +246,28 @@ def drain_gnn_queue(fn, params, queue, node_budget: int, edge_budget: int,
     ``drain_gnn_queue_continuous`` for the latency-aware path."""
     from repro.core import gnn_model as G
     from repro.data import pipeline as P
-    packable, oversize, outcomes = _admit(
-        queue, node_budget, edge_budget,
-        can_fallback=fallback_fn is not None,
-        can_partition=partition_fn is not None, validate=validate)
-    batches, leftover = P.pack_dataset(packable, node_budget, edge_budget,
-                                       batch_graphs)
-    assert not leftover, "_admit already screened for budget fit"
-    outs, over_outs, over_status, stats = _launch_packed(
-        lambda b: fn(params, G.packed_to_device(b)), batches, oversize,
-        None if fallback_fn is None else (lambda el: fallback_fn(params, el)),
-        partition_fn=partition_fn,
-        graphs_in=lambda b: int(b["num_graphs"]),
-        slots_in=lambda b: int((b["node_graph_id"] < batch_graphs).sum()),
-        slot_capacity=len(batches) * node_budget)
-    _reconcile_oversize(outcomes, over_status)
-    return outs + [o for o in over_outs if o is not None], \
-        _rejection_stats(stats, outcomes)
+    t0 = time.perf_counter()
+    with trace.span("serve.drain", graphs=len(queue)):
+        with trace.span("serve.admit"):
+            packable, oversize, outcomes = _admit(
+                queue, node_budget, edge_budget,
+                can_fallback=fallback_fn is not None,
+                can_partition=partition_fn is not None, validate=validate)
+        with trace.span("pack.dataset"):
+            batches, leftover = P.pack_dataset(packable, node_budget,
+                                               edge_budget, batch_graphs)
+        assert not leftover, "_admit already screened for budget fit"
+        outs, over_outs, over_status, stats = _launch_packed(
+            lambda b: fn(params, G.packed_to_device(b)), batches, oversize,
+            None if fallback_fn is None
+            else (lambda el: fallback_fn(params, el)),
+            partition_fn=partition_fn,
+            graphs_in=lambda b: int(b["num_graphs"]),
+            slots_in=lambda b: int((b["node_graph_id"] < batch_graphs).sum()),
+            slot_capacity=len(batches) * node_budget)
+        _reconcile_oversize(outcomes, over_status)
+        stats = _rejection_stats(_timed(stats, t0), outcomes)
+    return outs + [o for o in over_outs if o is not None], stats
 
 
 def drain_gnn_queue_sharded(fn, params, queue, node_budget: int,
@@ -278,31 +289,38 @@ def drain_gnn_queue_sharded(fn, params, queue, node_budget: int,
     screen)."""
     from repro.core import gnn_model as G
     from repro.data import pipeline as P
-    packable, oversize, outcomes = _admit(
-        queue, node_budget, edge_budget,
-        can_fallback=fallback_fn is not None,
-        can_partition=partition_fn is not None, validate=validate)
-    waves, leftover = P.pack_dataset(packable, node_budget, edge_budget,
-                                     batch_graphs, num_shards=num_shards)
-    assert not leftover, "_admit already screened for budget fit"
-    dev_outs, over_outs, over_status, stats = _launch_packed(
-        lambda w: fn(params, G.stack_shards(w)), waves, oversize,
-        None if fallback_fn is None else (lambda el: fallback_fn(params, el)),
-        partition_fn=partition_fn,
-        graphs_in=lambda w: w.n_graphs,
-        slots_in=lambda w: sum(int((b["node_graph_id"]
-                                    < batch_graphs).sum())
-                               for b in w.shards),
-        slot_capacity=len(waves) * num_shards * node_budget)
-    stats["num_shards"] = num_shards
-    _reconcile_oversize(outcomes, over_status)
-    if task == "graph":
-        outs = [P.gather_shard_outputs(np.asarray(o), w.index)
-                for w, o in zip(waves, dev_outs)]
-    else:
-        outs = dev_outs
-    return outs + [o for o in over_outs if o is not None], \
-        _rejection_stats(stats, outcomes)
+    t0 = time.perf_counter()
+    with trace.span("serve.drain", graphs=len(queue)):
+        with trace.span("serve.admit"):
+            packable, oversize, outcomes = _admit(
+                queue, node_budget, edge_budget,
+                can_fallback=fallback_fn is not None,
+                can_partition=partition_fn is not None, validate=validate)
+        with trace.span("pack.dataset"):
+            waves, leftover = P.pack_dataset(packable, node_budget,
+                                             edge_budget, batch_graphs,
+                                             num_shards=num_shards)
+        assert not leftover, "_admit already screened for budget fit"
+        dev_outs, over_outs, over_status, stats = _launch_packed(
+            lambda w: fn(params, G.stack_shards(w)), waves, oversize,
+            None if fallback_fn is None
+            else (lambda el: fallback_fn(params, el)),
+            partition_fn=partition_fn,
+            graphs_in=lambda w: w.n_graphs,
+            slots_in=lambda w: sum(int((b["node_graph_id"]
+                                        < batch_graphs).sum())
+                                   for b in w.shards),
+            slot_capacity=len(waves) * num_shards * node_budget)
+        stats["num_shards"] = num_shards
+        _reconcile_oversize(outcomes, over_status)
+        if task == "graph":
+            with trace.span("pack.gather_shards"):
+                outs = [P.gather_shard_outputs(np.asarray(o), w.index)
+                        for w, o in zip(waves, dev_outs)]
+        else:
+            outs = dev_outs
+        stats = _rejection_stats(_timed(stats, t0), outcomes)
+    return outs + [o for o in over_outs if o is not None], stats
 
 
 def _partition_or_infeasible(partition_fn, g):

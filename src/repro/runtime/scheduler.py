@@ -65,6 +65,7 @@ import time
 import numpy as np
 
 from repro.data import pipeline as P
+from repro.runtime import trace
 from repro.runtime.elastic import plan_mesh_shape, pool_plan
 from repro.runtime.straggler import StragglerDetector
 
@@ -493,30 +494,33 @@ class ContinuousScheduler:
         Always returns the request id; exactly one Response will
         eventually carry it. Check order: malformed input (when
         ``cfg.validate``), oversize with no fallback lane, queue bound."""
-        now = self.clock.now()
         rid = self._next_id
         self._next_id += 1
-        if self.cfg.validate:
-            reason = P.validate_graph(graph)
-            if reason is not None:
-                self.responses.append(Response(rid, tenant, REJECTED_INVALID,
-                                               now))
-                self.events.append({"t": now, "kind": "rejected_invalid",
-                                    "req_id": rid, "reason": reason})
+        with trace.span("sched.submit", req_id=rid):
+            now = self.clock.now()
+            if self.cfg.validate:
+                reason = P.validate_graph(graph)
+                if reason is not None:
+                    self.responses.append(Response(
+                        rid, tenant, REJECTED_INVALID, now))
+                    self.events.append({"t": now, "kind": "rejected_invalid",
+                                        "req_id": rid, "reason": reason})
+                    return rid
+            fits = P.graph_fits_budget(graph, self.cfg.node_budget,
+                                       self.cfg.edge_budget)
+            if not fits and not (self._can_partition()
+                                 or self._can_fallback()):
+                self.responses.append(Response(rid, tenant,
+                                               REJECTED_OVERSIZE, now))
                 return rid
-        fits = P.graph_fits_budget(graph, self.cfg.node_budget,
-                                   self.cfg.edge_budget)
-        if not fits and not (self._can_partition() or self._can_fallback()):
-            self.responses.append(Response(rid, tenant, REJECTED_OVERSIZE,
-                                           now))
+            if self._depth.get(tenant, 0) >= self.cfg.max_queue_depth:
+                self.responses.append(Response(rid, tenant, REJECTED_QUEUE,
+                                               now))
+                return rid
+            self.pending.append(Request(rid, graph, tenant, now))
+            self._depth[tenant] = self._depth.get(tenant, 0) + 1
+            self._launch_ready(now)      # budget-full may fire immediately
             return rid
-        if self._depth.get(tenant, 0) >= self.cfg.max_queue_depth:
-            self.responses.append(Response(rid, tenant, REJECTED_QUEUE, now))
-            return rid
-        self.pending.append(Request(rid, graph, tenant, now))
-        self._depth[tenant] = self._depth.get(tenant, 0) + 1
-        self._launch_ready(now)          # budget-full may fire immediately
-        return rid
 
     # ----------------------------------------------------------- event loop
     def next_event_s(self) -> float | None:
@@ -663,9 +667,11 @@ class ContinuousScheduler:
         return [r for r in self.pending if r.not_before_s <= now + 1e-12]
 
     def _ordered_pending(self, now: float) -> list:
-        return sorted(self._ready_pending(now),
-                      key=lambda r: (-self._tier(r.tenant).priority,
-                                     r.arrival_s, r.req_id))
+        ready = self._ready_pending(now)
+        trace.count("sched.selects")
+        trace.count("sched.scanned", len(ready))
+        return sorted(ready, key=lambda r: (-self._tier(r.tenant).priority,
+                                            r.arrival_s, r.req_id))
 
     def _earliest_due_s(self, now: float) -> float:
         return min(max(r.arrival_s + self._tier(r.tenant).deadline_s,
@@ -761,13 +767,16 @@ class ContinuousScheduler:
             kind, reqs = "packed", sel.requests
             for r in reqs:
                 self._remove_pending(r)
-            batch, k = P.pack_graphs([r.graph for r in reqs],
-                                     self.cfg.node_budget,
-                                     self.cfg.edge_budget,
-                                     self.cfg.max_graphs)
+            with trace.span("pack.graphs", seq=self._seq,
+                            graphs=len(reqs)):
+                batch, k = P.pack_graphs([r.graph for r in reqs],
+                                         self.cfg.node_budget,
+                                         self.cfg.edge_budget,
+                                         self.cfg.max_graphs)
             assert k == len(reqs), "selection must fit the budgets"
             try:
-                out, svc = executor.run_batch(batch)
+                with trace.span("exec.run_batch", seq=self._seq):
+                    out, svc = executor.run_batch(batch)
             except Exception as e:     # noqa: BLE001 — lane fault, not ours
                 out, svc = None, 0.0
                 error, after_s = self._crash(e)
