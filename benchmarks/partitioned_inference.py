@@ -112,7 +112,7 @@ def worker(num_devices: int, repeats: int) -> dict:
     mesh = make_data_mesh(num_devices)
     fn = G.make_partitioned_apply(cfg, mesh, None, None,
                                   out_rows=part.padded_nodes)
-    stacked = G.stack_shards(part.parts)
+    stacked = G.stack_shards(part.parts, mesh)
     el = {"node_feat": jnp.asarray(g.node_feat),
           "edge_index": jnp.asarray(g.edge_index),
           "edge_feat": jnp.asarray(g.edge_feat),

@@ -86,14 +86,14 @@ def worker(num_shards: int, n_graphs: int, batch_graphs: int,
     single_fn = jax.jit(lambda p, b: G.apply_packed(p, cfg, b))
 
     # parity: each shard of the first wave vs the single-device program
-    stacked0 = G.stack_shards(waves[0])
+    stacked0 = G.stack_shards(waves[0], mesh)
     out0 = np.asarray(fn(params, stacked0))
     max_err = 0.0
     for s, shard in enumerate(waves[0].shards):
         ref = np.asarray(single_fn(params, G.packed_to_device(shard)))
         max_err = max(max_err, float(np.abs(out0[s] - ref).max()))
 
-    stacked = [G.stack_shards(w) for w in waves]
+    stacked = [G.stack_shards(w, mesh) for w in waves]
     for b in stacked:                                   # compile/warmup
         jax.block_until_ready(fn(params, b))
     n_served = sum(w.n_graphs for w in waves)

@@ -213,7 +213,6 @@ def four_chip_phase(requests: int = REQUESTS,
     from repro.core import gnn_model as G
     from repro.core import quantization as Q
     from repro.data import pipeline as P
-    from repro.distributed.sharding import graph_batch_sharding
     from repro.launch import serve
     from repro.launch.mesh import make_data_mesh
     from repro.nn import param as prm
@@ -245,8 +244,7 @@ def four_chip_phase(requests: int = REQUESTS,
                                   num_shards=n_dev)
         worst = 0.0
         for w in waves:
-            stacked = jax.device_put(G.stack_shards(w),
-                                     graph_batch_sharding(mesh))
+            stacked = G.stack_shards(w, mesh)
             in_devs = {d for x in jax.tree_util.tree_leaves(stacked)
                        for d in x.devices()}
             out = sharded(params, stacked)
@@ -276,8 +274,7 @@ def four_chip_phase(requests: int = REQUESTS,
             max_edges=max(ds.max_edges, 4 * eb), seed=ds.seed + 0x0B1)
         g = P.make_graph(big, 0)
         part = P.partition_graph(g, n_dev, nb, eb)
-        stacked = jax.device_put(G.stack_shards(part.parts),
-                                 graph_batch_sharding(mesh))
+        stacked = G.stack_shards(part.parts, mesh)
         in_devs = {d for x in jax.tree_util.tree_leaves(stacked)
                    for d in x.devices()}
         t0 = time.perf_counter()

@@ -27,9 +27,12 @@ every op) is kept as the paper's original testbench semantic.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import convs as C
 from repro.core import quantization as Q
@@ -174,11 +177,102 @@ def graph_inputs(batch_el: dict) -> tuple:
     return g, x, node_mask
 
 
+def _word_array(key, v):
+    """Leaf ``key`` as the host array ``jnp.asarray`` would transfer
+    (8-byte dtypes narrowed by ``jax.dtypes.canonicalize_dtype``).
+    Raises TypeError for a leaf that cannot be laid in 4-byte words:
+    not a numpy array or scalar, not bool/int/float, or wider than 4
+    bytes after canonicalization."""
+    if isinstance(v, (np.ndarray, np.generic)) and v.dtype.kind in "biuf":
+        dt = np.dtype(jax.dtypes.canonicalize_dtype(v.dtype))
+        if dt.itemsize <= 4:
+            return np.asarray(v).astype(dt, copy=False)
+    raise TypeError(f"leaf {key!r} ({type(v).__name__} of "
+                    f"{getattr(v, 'dtype', None)}) cannot be laid in "
+                    "4-byte words: give a host numpy bool/int/float array")
+
+
+@functools.lru_cache(maxsize=64)
+def _split_program(layout: tuple, sharding):
+    """The jitted program that slices every leaf of ``layout`` (a tuple
+    of (key, shape, dtype, offset)) out of a word buffer (W,) — or
+    (num_shards, W), one row per shard — and restores its shape and
+    dtype bit for bit. Cached per layout, so it compiles once per batch
+    shape; ``sharding`` places the outputs (the stacked path's
+    ``graph_batch_sharding``, None for one batch)."""
+    def split(words):
+        out = {}
+        for key, shape, dtype, off in layout:
+            w = jax.lax.slice_in_dim(words, off, off + math.prod(shape),
+                                     axis=words.ndim - 1)
+            if dtype == np.bool_:
+                x = w != 0
+            elif dtype.itemsize == 4:
+                x = jax.lax.bitcast_convert_type(w, dtype)
+            else:
+                x = jax.lax.bitcast_convert_type(
+                    w.astype(f"uint{8 * dtype.itemsize}"), dtype)
+            out[key] = x.reshape(words.shape[:-1] + shape)
+        return out
+    if sharding is None:
+        return jax.jit(split)
+    return jax.jit(split, out_shardings=sharding)
+
+
+def _word_layout(batches):
+    """The word layout of ``batches`` (same-shape GraphBatch dicts, ``y``
+    left out): ``(layout, host, width)`` with ``layout`` the split
+    program's (key, shape, dtype, offset) tuple, ``host`` each key's
+    host arrays, one per batch, and ``width`` the words per batch."""
+    layout, host, width = [], {}, 0
+    for k in batches[0]:
+        if k == "y":
+            continue
+        arrs = [_word_array(k, b[k]) for b in batches]
+        a0 = arrs[0]
+        if any(a.shape != a0.shape or a.dtype != a0.dtype for a in arrs):
+            raise ValueError(f"leaf {k!r} differs in shape or dtype "
+                             "between shards")
+        layout.append((k, a0.shape, a0.dtype, width))
+        host[k] = arrs
+        width += a0.size
+    return tuple(layout), host, width
+
+
+def _batches_to_device(batches, sharding) -> dict:
+    """One host-to-device transfer of ``batches`` laid in words and one
+    split program. With ``sharding`` the words are stacked with a
+    leading shard dim, which ``sharding`` splits over the mesh."""
+    layout, host, width = _word_layout(batches)
+    words = np.empty((len(batches), width), np.uint32)
+    for k, shape, dtype, off in layout:
+        unsigned = np.dtype(f"uint{8 * dtype.itemsize}")
+        for row, a in zip(words, host[k]):
+            row[off:off + a.size] = a.reshape(-1).view(unsigned)
+    if sharding is None:
+        dev = jax.device_put(words[0])
+    else:
+        dev = jax.device_put(words, sharding)
+    trace.count("put.buffers", 1)
+    out = _split_program(layout, sharding)(dev)
+    return {k: out[k] for k, *_ in layout}       # jit sorts dict keys
+
+
 def packed_to_device(batch: dict) -> dict:
     """Host GraphBatch -> device arrays, stripping the host-only target
-    buffer ``y`` so it is never traced into the inference program."""
+    buffer ``y`` so it is never traced into the inference program.
+
+    The leaves travel as one buffer: each is laid end to end in a
+    fresh host array of 4-byte words (4-byte dtypes by ``.view``, bool
+    and narrower ints widened, 8-byte dtypes first narrowed as
+    ``jnp.asarray`` narrows them), sent with one ``jax.device_put``
+    and split on the device by one jitted program cached per layout.
+    The result is the dict ``jnp.asarray`` per leaf would give: same
+    keys, shapes and dtypes, bit-identical values. Every leaf must be
+    a host numpy bool/int/float array or scalar (TypeError otherwise),
+    as every packer of ``data.pipeline`` makes them."""
     with trace.span("device.put"):
-        return {k: jnp.asarray(v) for k, v in batch.items() if k != "y"}
+        return _batches_to_device([batch], None)
 
 
 def packed_inputs(batch: dict) -> tuple:
@@ -454,15 +548,23 @@ def apply_packed_resident(params, cfg: GNNModelConfig, batch: dict,
     return out
 
 
-def stack_shards(shards) -> dict:
+def stack_shards(shards, mesh) -> dict:
     """Host ShardedBatch shards -> one stacked device-ready dict with a
     leading shard dim (num_shards, ...), stripping the host-only ``y``
     like ``packed_to_device``. Accepts a ShardedBatch or a plain list of
-    same-shape GraphBatch dicts."""
+    same-shape GraphBatch dicts.
+
+    The shards travel as one (num_shards, W) word array, one row per
+    shard laid out as in ``packed_to_device``. ``mesh`` is the 1-D
+    ("data",) mesh of num_shards devices that the consuming program was
+    built over: one ``jax.device_put`` under
+    ``graph_batch_sharding(mesh)`` lands each row on its own device and
+    the split program's outputs carry that sharding, so
+    ``make_sharded_apply``'s program moves nothing."""
+    from repro.distributed.sharding import graph_batch_sharding
     shards = getattr(shards, "shards", shards)
     with trace.span("device.put"):
-        return {k: jnp.stack([jnp.asarray(b[k]) for b in shards])
-                for k in shards[0] if k != "y"}
+        return _batches_to_device(shards, graph_batch_sharding(mesh))
 
 
 def make_sharded_apply(cfg: GNNModelConfig, mesh,
@@ -482,7 +584,8 @@ def make_sharded_apply(cfg: GNNModelConfig, mesh,
 
     Trace-time state (the aggregation backend scope) is baked in on the
     first call, like ``apply_packed`` under jit. Hold on to the returned
-    callable across waves so XLA compiles exactly once.
+    callable across waves so XLA compiles exactly once. It carries
+    ``mesh`` as ``.mesh``, for ``stack_shards`` to land waves on.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -496,8 +599,10 @@ def make_sharded_apply(cfg: GNNModelConfig, mesh,
     fn = jax.shard_map(per_shard, mesh=mesh,
                        in_specs=(P(), P("data")), out_specs=P("data"),
                        check_vma=False)
-    return jax.jit(fn, in_shardings=(replicated(mesh),
-                                     graph_batch_sharding(mesh)))
+    program = jax.jit(fn, in_shardings=(replicated(mesh),
+                                        graph_batch_sharding(mesh)))
+    program.mesh = mesh
+    return program
 
 
 def apply_packed_sharded(params, cfg: GNNModelConfig, shards, mesh=None,
@@ -508,10 +613,13 @@ def apply_packed_sharded(params, cfg: GNNModelConfig, shards, mesh=None,
     device. ``mesh=None`` builds the ("data",) mesh over the first
     num_shards local devices. Retraces on every call — serving and
     benchmark loops should hold on to ``make_sharded_apply`` instead."""
-    stacked = shards if isinstance(shards, dict) else stack_shards(shards)
     if mesh is None:
         from repro.launch.mesh import make_data_mesh
-        mesh = make_data_mesh(stacked["node_feat"].shape[0])
+        mesh = make_data_mesh(
+            shards["node_feat"].shape[0] if isinstance(shards, dict)
+            else len(getattr(shards, "shards", shards)))
+    stacked = (shards if isinstance(shards, dict)
+               else stack_shards(shards, mesh))
     return make_sharded_apply(cfg, mesh, quant, policy)(params, stacked)
 
 
@@ -646,10 +754,10 @@ def apply_packed_partitioned(params, cfg: GNNModelConfig, partition,
     serving loops can call this per request without recompiling."""
     parts = getattr(partition, "parts", partition)
     out_rows = getattr(partition, "padded_nodes", 0) or None
-    stacked = stack_shards(parts)
     if mesh is None:
         from repro.launch.mesh import make_data_mesh
         mesh = make_data_mesh(len(parts))
+    stacked = stack_shards(parts, mesh)
     key = (id(cfg), id(mesh), id(quant), id(policy), out_rows, len(parts))
     hit = _PARTITIONED_PROGRAMS.get(key)
     if hit is None:

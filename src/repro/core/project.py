@@ -456,7 +456,7 @@ class Project:
         waves, dropped = data_mod.pack_dataset(
             self._tb_graphs, self.node_budget, self.edge_budget,
             self.batch_graphs, num_shards=self.num_shards)
-        stacked = [G.stack_shards(w) for w in waves]
+        stacked = [G.stack_shards(w, mesh) for w in waves]
         for b in stacked:                           # warmup / compile
             jax.block_until_ready(fn(params, b))
         t0 = time.perf_counter()

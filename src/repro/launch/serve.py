@@ -286,7 +286,9 @@ def drain_gnn_queue_sharded(fn, params, queue, node_budget: int,
     ``drain_gnn_queue`` (same ``_launch_packed`` body: partitioned SPMD
     program first, padded fallback second, explicit rejection last),
     and so do the per-request rejection outcomes (same ``_admit``
-    screen)."""
+    screen). Each wave reaches the devices as one transfer that lands
+    every shard on its device of ``fn.mesh``, the mesh that
+    ``make_sharded_apply`` built ``fn``'s program over."""
     from repro.core import gnn_model as G
     from repro.data import pipeline as P
     t0 = time.perf_counter()
@@ -302,7 +304,8 @@ def drain_gnn_queue_sharded(fn, params, queue, node_budget: int,
                                              num_shards=num_shards)
         assert not leftover, "_admit already screened for budget fit"
         dev_outs, over_outs, over_status, stats = _launch_packed(
-            lambda w: fn(params, G.stack_shards(w)), waves, oversize,
+            lambda w: fn(params, G.stack_shards(w, fn.mesh)), waves,
+            oversize,
             None if fallback_fn is None
             else (lambda el: fallback_fn(params, el)),
             partition_fn=partition_fn,

@@ -179,13 +179,32 @@ def sharded_parity_script():
                            node_feat_dim=7, edge_feat_dim=3, seed=5)
     graphs = [P.make_graph(DS, i) for i in range(9)]   # uneven over 2
 
+    import tempfile
+    from jax.sharding import NamedSharding, PartitionSpec as PS
+    from repro.launch import serve
+    from repro.runtime import trace
+
+    def per_leaf_stack(w):
+        return {k: jnp.stack([jnp.asarray(b[k]) for b in w.shards])
+                for k in w.shards[0] if k != "y"}
+
     mesh2 = make_data_mesh(2)
+    wave, k = P.shard_pack(graphs, 96, 192, 8, num_shards=2)
+    assert k == len(graphs)
+    # one transfer lands each shard on its device, bit for bit the
+    # per-leaf stack, already under the sharded program's placement
+    stacked = G.stack_shards(wave, mesh2)
+    ref_stack = per_leaf_stack(wave)
+    assert list(stacked) == list(ref_stack)
+    for key, v in ref_stack.items():
+        assert (stacked[key].dtype, stacked[key].shape) == (v.dtype, v.shape)
+        assert np.asarray(stacked[key]).tobytes() == \
+            np.asarray(v).tobytes(), key
+        assert stacked[key].sharding == NamedSharding(mesh2, PS("data")), \
+            (key, stacked[key].sharding)
     for conv in CONVS:
         cfg = model_cfg(conv)
         params = prm.materialize(G.model_plan(cfg), jax.random.key(0))
-        wave, k = P.shard_pack(graphs, 96, 192, 8, num_shards=2)
-        assert k == len(graphs)
-        stacked = G.stack_shards(wave)
         cal_batch, _ = P.pack_graphs(graphs, 192, 384, 16)
         for precision in precisions(conv):
             policy = G.calibrated_policy(
@@ -209,6 +228,19 @@ def sharded_parity_script():
         for i, g in enumerate(graphs):
             ref = np.asarray(oracle(params, el(g)))
             assert np.abs(host[i] - ref).max() < 1e-4, (conv, i)
+        # the sharded drain's outputs are those of the per-leaf stack
+        trace.reset()
+        with jax.profiler.trace(tempfile.mkdtemp()):
+            outs, stats = serve.drain_gnn_queue_sharded(
+                fn, params, graphs, 96, 192, 8, 2)
+        counters = trace.snapshot()["counters"]
+        waves, _ = P.pack_dataset(graphs, 96, 192, 8, num_shards=2)
+        assert len(outs) == len(waves) == stats["n_batches"]
+        for w, o in zip(waves, outs):
+            ref = P.gather_shard_outputs(
+                np.asarray(fn(params, per_leaf_stack(w))), w.index)
+            assert np.array_equal(o, ref), conv
+        assert counters == {"put.buffers": len(waves)}, counters
         # 4-shard wave with idle shards: one graph, three empty blocks
         wave4, k4 = P.shard_pack(graphs[:1], 96, 192, 8, num_shards=4)
         assert k4 == 1
@@ -231,8 +263,8 @@ def partitioned_parity_script():
                            seed=11)
     g = P.make_graph(DS, 0)
     part4 = P.partition_graph(g, 4, 64, 128)
-    stacked4 = G.stack_shards(part4.parts)
     mesh4 = make_data_mesh(4)
+    stacked4 = G.stack_shards(part4.parts, mesh4)
     eg = el(g)
 
     for conv in CONVS:

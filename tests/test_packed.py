@@ -12,6 +12,7 @@ from repro.core.convs import CONV_TYPES
 from repro.core.pooling import POOLINGS, global_pool, segment_global_pool
 from repro.data import pipeline as P
 from repro.nn import param as prm
+from repro.runtime import trace
 
 DS = P.GraphDataConfig(avg_nodes=10, max_nodes=64, max_edges=64,
                        node_feat_dim=11, edge_feat_dim=4, seed=5)
@@ -239,3 +240,86 @@ def test_size_budget_rule():
     assert P.size_budget(32, 18) % 8 == 0
     assert P.size_budget(32, 18) >= 32 * 18      # slack over the mean
     assert P.size_budget(1, 1) >= 1
+
+
+# ------------------------------------------------ host-to-device transfer --
+def _qm9_batch():
+    batch, k = P.pack_graphs(_graphs(), 128, 256, 8)
+    assert k == len(_graphs())
+    return batch
+
+
+def _partition_part():
+    g = P.make_graph(P.GraphDataConfig(avg_nodes=40, avg_degree=2,
+                                       node_feat_dim=7, edge_feat_dim=3,
+                                       max_nodes=128, max_edges=192,
+                                       seed=11), 0)
+    part = P.partition_graph(g, 2, 96, 160).parts[0]
+    assert {"node_in_deg", "node_out_deg"} <= set(part)
+    return part
+
+
+def _wide_dtypes_batch():
+    """float64 and int64 leaves, which ``jnp.asarray`` narrows to
+    float32 / int32 (values that round, and negative ints)."""
+    batch = _qm9_batch()
+    rng = np.random.default_rng(3)
+    batch["node_feat"] = rng.standard_normal(batch["node_feat"].shape)
+    batch["edge_index"] = batch["edge_index"].astype(np.int64)
+    return batch
+
+
+def _narrow_dtypes_batch():
+    """Leaves narrower than a word: int8, uint16 and float16 with a NaN
+    payload, beside bool."""
+    batch = _qm9_batch()
+    batch["node_code"] = np.arange(-64, 64, dtype=np.int8)
+    batch["edge_tag"] = np.arange(65530, 65536, dtype=np.uint16)
+    half = np.array([1.5, -0.0, np.inf, 65504.0], np.float16)
+    half = np.concatenate([half, np.array([0x7D01], np.uint16)
+                           .view(np.float16)])
+    batch["edge_half"] = half
+    return batch
+
+
+@pytest.mark.parametrize("make", [
+    lambda: P.empty_graph_batch(64, 128, 4, 11, 4),
+    _qm9_batch,
+    _partition_part,
+    _wide_dtypes_batch,
+    _narrow_dtypes_batch,
+], ids=["empty", "qm9", "partition_part", "wide_dtypes", "narrow_dtypes"])
+def test_packed_to_device_matches_per_leaf_transfer(make, tmp_path):
+    """The single-buffer transfer returns what ``jnp.asarray`` per leaf
+    returns: same keys in the same order, shapes, dtypes and weak types,
+    bit-identical values, and ``y`` stripped. One buffer carries every
+    leaf."""
+    batch = make()
+    ref = {k: jnp.asarray(v) for k, v in batch.items() if k != "y"}
+    trace.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        got = G.packed_to_device(batch)
+    counters = trace.snapshot()["counters"]
+    trace.reset()
+    assert counters == {"put.buffers": 1}
+    assert "y" not in got
+    assert list(got) == list(ref)
+    for k in ref:
+        a, b = np.asarray(ref[k]), np.asarray(got[k])
+        assert (b.dtype, b.shape) == (a.dtype, a.shape), k
+        assert got[k].weak_type == ref[k].weak_type, k
+        assert b.tobytes() == a.tobytes(), k
+
+
+@pytest.mark.parametrize("leaf", [
+    jnp.arange(5, dtype=jnp.int32),
+    np.exp(1j * np.arange(4)).astype(np.complex64),
+    3,
+], ids=["jax_array", "complex", "python_int"])
+def test_packed_to_device_refuses_leaves_not_in_words(leaf):
+    """A leaf that cannot be laid in 4-byte words (already on the
+    device, complex, not a numpy value) is refused by name."""
+    batch = _qm9_batch()
+    batch["extra"] = leaf
+    with pytest.raises(TypeError, match="'extra'"):
+        G.packed_to_device(batch)

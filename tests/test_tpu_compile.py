@@ -242,6 +242,30 @@ def test_sharded_program_compiles_on_4_devices(mesh4):
     assert len(c.output_shardings.device_set) == 4
 
 
+@pytest.mark.parametrize("shards", [1, 4])
+def test_transfer_split_compiles(one_chip, mesh4, shards):
+    """The split program of the one-buffer transfer, for a packed batch
+    on one chip and for a wave of four shards landed on a 4-device mesh:
+    there every output keeps the ("data",) placement and nothing moves
+    between devices."""
+    from jax.sharding import NamedSharding, PartitionSpec as PS
+
+    from repro.core import gnn_model as G
+    layout, _, width = G._word_layout([_qm9_batch()] * shards)
+    if shards == 1:
+        G._split_program(layout, None).lower(
+            _spec((width,), np.uint32, one_chip)).compile()
+        return
+    data = NamedSharding(mesh4, PS("data"))
+    c = G._split_program(layout, data).lower(
+        _spec((shards, width), np.uint32, data)).compile()
+    assert all(s == data for s in jax.tree_util.tree_leaves(
+        c.output_shardings))
+    text = c.as_text()
+    assert not any(op in text for op in (
+        "all-gather", "all-to-all", "collective-permute", "all-reduce"))
+
+
 def test_partitioned_program_compiles_on_4_devices(mesh4):
     from jax.sharding import NamedSharding, PartitionSpec as PS
 

@@ -168,11 +168,14 @@ def test_drain_spans_one_per_stage_and_one_per_batch(program, tmp_path):
 
 
 def test_sharded_drain_spans_the_gather(tmp_path):
+    from repro.launch.mesh import make_data_mesh
     shards = 2
 
     def fn(params, stacked):                     # stand-in for the SPMD
         return jnp.zeros(stacked["graph_valid"].shape + (1,))
 
+    # the stand-in's mesh: this process's one device holds both shards
+    fn.mesh = make_data_mesh(1)
     queue = _queue()
     with jax.profiler.trace(str(tmp_path)):
         outs, stats = serve.drain_gnn_queue_sharded(
@@ -185,6 +188,26 @@ def test_sharded_drain_spans_the_gather(tmp_path):
         assert spans[name]["n"] == 1, name
     assert spans["device.put"]["n"] == n
     assert spans["device.launch"]["n"] == n
+    assert trace.snapshot()["counters"]["put.buffers"] == n
+
+
+def test_put_is_one_buffer_per_launch(program, tmp_path):
+    """Each launch opens one ``device.put`` span and sends its packed
+    batch as one host-to-device buffer, in the wave drain and under the
+    scheduler."""
+    queue = _queue()
+    _drain(program, queue)                       # compile off the record
+    _run_scheduler(_scheduler(program), queue)
+    sched = _scheduler(program)
+    with jax.profiler.trace(str(tmp_path)):
+        _, stats = _drain(program, queue)
+        _run_scheduler(sched, queue)
+    snap = trace.snapshot()
+    launches = stats["n_batches"] + sum(
+        1 for l in sched.launches if l["kind"] == "packed")
+    assert snap["spans"]["device.put"]["n"] == launches
+    assert snap["spans"]["device.launch"]["n"] == stats["n_batches"]
+    assert snap["counters"]["put.buffers"] == launches
 
 
 def test_scheduler_spans_submits_and_launches(program, tmp_path):
