@@ -31,7 +31,6 @@ HERE = Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE), str(HERE.parents[1] / "src")]
 
 import bench_harness as H  # noqa: E402
-import bench_molecules  # noqa: E402
 import bench_run as R  # noqa: E402
 
 
@@ -39,11 +38,10 @@ def reading(cell: H.Cell, config: dict, seed: int, seconds: float,
             require_chip: bool = True) -> dict:
     """Drive the program of ``config`` through the cell's traffic and
     return its answers with the pool they came from."""
-    traffic = cell.traffic
-    pool_mols = bench_molecules.make_pool(config["molecules"], seed,
-                                          traffic["pool_graphs"])
+    traffic, family = cell.traffic, cell.family
+    pool_mols = family.make_pool(config, seed, traffic["pool_graphs"])
     pool = [H.to_graph(m) for m in pool_mols]
-    sut = H.Sut(config, H.make_weights(config["model"], seed),
+    sut = H.Sut(family, config, H.make_weights(family, config["model"], seed),
                 shards=int(traffic.get("shards", 1)))
     if traffic["mode"] == "screen":
         H.screen_warmup(sut, pool, traffic, seed)
@@ -59,11 +57,11 @@ def reading(cell: H.Cell, config: dict, seed: int, seconds: float,
 
 def seed_readings(cell: H.Cell, seed: int, seconds: float,
                   require_chip: bool = True) -> dict:
-    config = cell.config
+    config, family = cell.config, cell.family
     limits = config["check"]
     sound = reading(cell, config, seed, seconds, require_chip)
     ans, pool = sound["ans"], sound["pool"]
-    ref = H.reference_for(config, seed, pool, ans["idx"])
+    ref = H.reference_for(family, config, seed, pool, ans["idx"])
     every = dict(limits, max_err=0.0, rms_err=0.0)
 
     def errs(a, r):
@@ -72,13 +70,13 @@ def seed_readings(cell: H.Cell, seed: int, seconds: float,
 
     out = {"seed": seed, "served": ans["served"]}
     out["sound"], out["sound_rms"] = errs(ans, ref)
-    low = H.reference_for(config, seed, pool, ans["idx"], "bf16x3")
+    low = H.reference_for(family, config, seed, pool, ans["idx"], "bf16x3")
     ctrl = dict(ans, rows=[low[int(i)] for i in ans["idx"]])
     out["ref_bf16x3"], out["ref_bf16x3_rms"] = errs(ctrl, ref)
     bf16 = copy.deepcopy(config)
     bf16["precision"]["program"] = "bf16"
     prog = reading(cell, bf16, seed, seconds, require_chip)["ans"]
-    ref_b = H.reference_for(config, seed, pool, prog["idx"])
+    ref_b = H.reference_for(family, config, seed, pool, prog["idx"])
     out["program_bf16"], out["program_bf16_rms"] = errs(prog, ref_b)
     return out
 

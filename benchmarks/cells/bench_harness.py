@@ -15,10 +15,11 @@ configuration (``configs/<name>.json``) under a traffic mix
   benchmark drives on a wall clock. A request is timed from when it was
   due to when the benchmark sees its response.
 
-Requests draw, with the seed, from a pool of distinct generated
-molecules (``bench_molecules``). Every served request's output row is
-checked against the plain reference (``bench_reference``) after the
-window; see ``check``.
+The configuration's family (``families/<family>.py``, see
+``family_of``) builds, feeds, checks and counts its model: requests
+draw, with the seed, from a pool of distinct graphs that the family
+generates, and every served request's output row is checked against
+the family's plain reference after the window; see ``check``.
 """
 from __future__ import annotations
 
@@ -31,12 +32,12 @@ from pathlib import Path
 
 import numpy as np
 
-import bench_flops
-import bench_reference
 import bench_stats
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+CELLS_DIR = HERE.relative_to(ROOT)        # the benchmark's files under a root
+DEFAULT_FAMILY = "gnnbuilder"
 
 
 # ---------------------------------------------------------------- cells --
@@ -48,6 +49,7 @@ class Cell:
     traffic: dict
     end_to_end: list
     per_layer: list
+    family: object          # the configuration's family module
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -69,14 +71,30 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     wl = by_name[name]
     conf = {c["name"]: c for c in bench["configs"]}[wl["config"]]
     config = json.loads((root / conf["file"]).read_text())
-    traffic = json.loads((HERE / "traffic" / f"{wl['traffic']}.json")
-                         .read_text())
+    traffic = json.loads((root / CELLS_DIR / "traffic"
+                          / f"{wl['traffic']}.json").read_text())
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or name in m["workloads"]]
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
                  if _reported_in(m, name, names)]
-    return Cell(name, int(wl["chips"]), config, traffic, e2e, per_layer)
+    return Cell(name, int(wl["chips"]), config, traffic, e2e, per_layer,
+                family_of(config, root))
+
+
+def family_of(config: dict, root: Path = ROOT):
+    """The module of the family a configuration names (``gnnbuilder``
+    where it names none), ``families/<family>.py`` under ``root``'s
+    benchmark directory: the five functions through which the harness
+    builds, feeds, checks and counts a model (their contract is in the
+    docstrings of ``families/gnnbuilder.py``). The module is loaded anew
+    on each call; ``load_cell`` loads it once and keeps it on
+    ``Cell.family``. Only configurations, traffic files and families
+    follow ``root``: per-layer readers come from this directory's
+    ``metrics/`` (``load_reader``)."""
+    name = config.get("family", DEFAULT_FAMILY)
+    return _load_file(root / CELLS_DIR / "families" / f"{name}.py",
+                      "bench_family_")
 
 
 def norm_seed(seed: int) -> int:
@@ -91,37 +109,16 @@ def weight_key(seed: int):
     return jax.random.fold_in(jax.random.key(int(words[0])), int(words[1]))
 
 
-def make_weights(model: dict, seed: int):
+def make_weights(family, model: dict, seed: int):
     """The model's weights on the device, in one jitted call."""
     import jax
-    return jax.jit(lambda k: bench_reference.init_params(model, k))(
-        weight_key(seed))
+    return jax.jit(lambda k: family.init_params(model, k))(weight_key(seed))
 
 
 # ---------------------------------------------------- system under test --
 def model_config(config: dict):
-    """The program's ``GNNModelConfig`` for a configuration file."""
-    from repro.core.gnn_model import GNNModelConfig, MLPConfig
-    m = config["model"]
-    par = m["parallelism"]
-    return GNNModelConfig(
-        graph_input_feature_dim=m["node_feat_dim"],
-        graph_input_edge_dim=m["edge_feat_dim"],
-        gnn_hidden_dim=m["hidden_dim"], gnn_num_layers=m["num_layers"],
-        gnn_output_dim=m["output_dim"], gnn_conv=m["conv"],
-        gnn_activation=m["activation"],
-        gnn_skip_connection=m["skip_connection"],
-        global_pooling=tuple(m["global_pooling"]),
-        mlp_head=MLPConfig(
-            in_dim=m["output_dim"] * len(m["global_pooling"]),
-            out_dim=m["num_targets"], hidden_dim=m["mlp_hidden_dim"],
-            hidden_layers=m["mlp_hidden_layers"],
-            activation=m["mlp_activation"], p_in=par["mlp_p_in"],
-            p_hidden=par["mlp_p_hidden"], p_out=par["mlp_p_out"]),
-        gnn_p_in=par["gnn_p_in"], gnn_p_hidden=par["gnn_p_hidden"],
-        gnn_p_out=par["gnn_p_out"], pna_delta=m["pna_delta"],
-        gnn_dataflow=m["dataflow"], avg_degree=m["avg_degree"],
-        gnn_precision=config["precision"]["program"])
+    """The program's model configuration for a configuration file."""
+    return family_of(config).program_config(config)
 
 
 def to_graph(mol: dict):
@@ -146,11 +143,11 @@ class Sut:
     sharded program over a ``("data",)`` mesh) behind ``serve``'s wave
     drains, or a ``ContinuousScheduler`` over a ``MeasuredExecutor``."""
 
-    def __init__(self, config: dict, params, shards: int = 1):
+    def __init__(self, family, config: dict, params, shards: int = 1):
         import jax
         from repro.core import gnn_model as G
         sv = config["serving"]
-        self.cfg = model_config(config)
+        self.cfg = family.program_config(config)
         self.node_budget = sv["node_budget"]
         self.edge_budget = sv["edge_budget"]
         self.batch_graphs = sv["batch_graphs"]
@@ -426,18 +423,16 @@ def online_answers(rec: dict) -> dict:
 
 
 # ------------------------------------------------------------- check --
-def reference_for(config: dict, seed: int, pool_mols: list, idx,
+def reference_for(family, config: dict, seed: int, pool_mols: list, idx,
                   precision: str = "highest") -> dict:
-    """Reference output of every distinct pool molecule in ``idx``."""
+    """Reference output of every distinct pool graph in ``idx``."""
     uniq = np.unique(np.asarray(idx, np.int64))
     if not len(uniq):
         return {}
-    params = make_weights(config["model"], seed)
-    ref = config["reference"]
-    out = bench_reference.reference_outputs(
-        params, config["model"], [pool_mols[i] for i in uniq],
-        node_pad=ref["node_pad"], edge_pad=ref["edge_pad"],
-        block_graphs=ref["block_graphs"], precision=precision)
+    params = make_weights(family, config["model"], seed)
+    out = family.reference_outputs(
+        params, config, [pool_mols[i] for i in uniq], precision=precision,
+        **config["reference"])
     return dict(zip(uniq.tolist(), out))
 
 
@@ -445,8 +440,8 @@ def compare(ans: dict, ref: dict, limits: dict) -> dict:
     """The numbers that decide ``correct``, each with its limit.
 
     ``max_err``: the widest gap between a served row and the reference
-    row of its molecule, over every served request, as a share of the
-    root mean square of the reference outputs of the molecules served.
+    row of its graph, over every served request, as a share of the
+    root mean square of the reference outputs of the graphs served.
     ``rms_err``: the root mean square of those gaps, on the same scale.
     ``lost``: requests the window made that got no answer, or more than
     one. ``unserved``: requests refused or failed. Only the numbers that
@@ -489,15 +484,19 @@ def end_to_end(cell: Cell, ans: dict, setup_s: float):
     return out
 
 
-def load_reader(name: str):
-    """The per-layer metric's reader, ``metrics/<name>.py``."""
+def _load_file(path: Path, prefix: str):
+    """A module of the benchmark's own, loaded by its path."""
     import importlib.util
-    path = HERE / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str):
+    """The per-layer metric's reader, ``metrics/<name>.py``."""
+    return _load_file(HERE / "metrics" / f"{name}.py", "bench_metric_").read
 
 
 @dataclasses.dataclass
@@ -520,14 +519,14 @@ def per_layer(view: RunView) -> dict:
     return out
 
 
-def served_flops(model: dict, pool_mols: list, idx) -> float:
+def served_flops(family, model: dict, pool_mols: list, idx) -> float:
     per = {}
     total = 0
     for i in np.asarray(idx, np.int64).tolist():
         if i not in per:
             m = pool_mols[i]
-            per[i] = bench_flops.graph_flops(model, m["num_nodes"],
-                                             m["num_edges"])
+            per[i] = family.graph_flops(model, m["num_nodes"],
+                                        m["num_edges"])
         total += per[i]
     return float(total)
 

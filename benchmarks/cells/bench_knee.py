@@ -25,7 +25,6 @@ HERE = Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE), str(HERE.parents[1] / "src")]
 
 import bench_harness as H  # noqa: E402
-import bench_molecules  # noqa: E402
 import bench_stats  # noqa: E402
 import bench_run as R  # noqa: E402
 
@@ -72,11 +71,10 @@ def main(argv=None) -> int:
     R.configure_jax(cell.config)
     R.devices_for(cell.chips, True)
     seed = H.norm_seed(args.seed)
-    config, traffic = cell.config, cell.traffic
-    pool_mols = bench_molecules.make_pool(config["molecules"], seed,
-                                          traffic["pool_graphs"])
+    config, traffic, family = cell.config, cell.traffic, cell.family
+    pool_mols = family.make_pool(config, seed, traffic["pool_graphs"])
     pool = [H.to_graph(m) for m in pool_mols]
-    sut = H.Sut(config, H.make_weights(config["model"], seed))
+    sut = H.Sut(family, config, H.make_weights(family, config["model"], seed))
     H.online_warmup(sut, pool, traffic, seed)
     gc.collect()
     gc.freeze()

@@ -11,7 +11,6 @@ import time
 from pathlib import Path
 
 import bench_harness as H
-import bench_molecules
 import bench_trace
 
 HERE = Path(__file__).resolve().parent
@@ -96,14 +95,14 @@ def run_cell(cell: H.Cell, seed: int, seconds: float, trace: bool, *,
     configure_jax(cell.config)
     kind = used[0].device_kind
     peak = peak_of(kind, require_chip)
-    config, traffic = cell.config, cell.traffic
+    config, traffic, family = cell.config, cell.traffic, cell.family
     seed_n = H.norm_seed(seed)
 
-    pool_mols = bench_molecules.make_pool(config["molecules"], seed_n,
-                                          traffic["pool_graphs"])
+    pool_mols = family.make_pool(config, seed_n, traffic["pool_graphs"])
     pool = [H.to_graph(m) for m in pool_mols]
-    params = H.make_weights(config["model"], seed_n)
-    sut = H.Sut(config, params, shards=int(traffic.get("shards", 1)))
+    params = H.make_weights(family, config["model"], seed_n)
+    sut = H.Sut(family, config, params,
+                shards=int(traffic.get("shards", 1)))
     if traffic["mode"] == "screen":
         H.screen_warmup(sut, pool, traffic, seed_n)
         driver, answers = H.run_screen, H.screen_answers
@@ -140,7 +139,7 @@ def run_cell(cell: H.Cell, seed: int, seconds: float, trace: bool, *,
     H.free_device_state(sut)
 
     t_ref = time.perf_counter()
-    ref = H.reference_for(config, seed_n, pool_mols, ans["idx"])
+    ref = H.reference_for(family, config, seed_n, pool_mols, ans["idx"])
     check = H.compare(ans, ref, config["check"])
     reference_s = time.perf_counter() - t_ref
     result = {"correct": H.passes(check), "attempted": ans["attempted"],
@@ -149,7 +148,8 @@ def run_cell(cell: H.Cell, seed: int, seconds: float, trace: bool, *,
         reduced = bench_trace.reduce_events(
             bench_trace.load_events(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
-        flops = H.served_flops(config["model"], pool_mols, ans["idx"])
+        flops = H.served_flops(family, config["model"], pool_mols,
+                               ans["idx"])
         view = H.RunView(cell, ans, reduced, flops, peak or {}, len(used))
         result["metrics"] = H.per_layer(view)
         if reduced is not None:

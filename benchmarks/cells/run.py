@@ -5,7 +5,7 @@
       --seconds 20 --trace 0
 
 Run from the root of a checkout on a machine that holds the chips the
-cell asks for (``BENCHMARK.json``). The run builds the cell's molecules
+cell asks for (``BENCHMARK.json``). The run builds the cell's graphs
 and weights from ``--seed``, warms up every program the window uses
 (that is ``setup_s``, counted from the start of the process), measures
 for ``--seconds``, then checks every served answer against the plain

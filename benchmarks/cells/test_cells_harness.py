@@ -217,7 +217,8 @@ def test_reference_matches_packed_program(conv, width):
         config = _tiny(config)
     mols = bench_molecules.make_pool(config["molecules"], 123, 40)
     with jax.default_matmul_precision("highest"):
-        params = H.make_weights(config["model"], 123)
+        params = H.make_weights(H.family_of(config), config["model"],
+                                123)
         got = _packed_program_outputs(config, params, mols)
         ref = config["reference"]
         want = bench_reference.reference_outputs(
@@ -233,11 +234,12 @@ def test_reference_weights_fit_the_programs_layout():
     from repro.core import gnn_model as G
     from repro.nn import param as prm
     for config in CONFIGS.values():
+        family = H.family_of(config)
         shapes = jax.tree_util.tree_map(
-            lambda a: a.shape, H.make_weights(config["model"], 0))
+            lambda a: a.shape, H.make_weights(family, config["model"], 0))
         plan = jax.tree_util.tree_map(
             lambda a: a.shape,
-            prm.abstract(G.model_plan(H.model_config(config))))
+            prm.abstract(G.model_plan(family.program_config(config))))
         assert shapes == plan
 
 
@@ -247,7 +249,7 @@ def test_reference_bf16x3_is_near_but_not_float32(conv):
     more than float32 rounding, by far less than one bfloat16 pass."""
     config = CONFIGS[f"{conv}-qm9"]
     mols = bench_molecules.make_pool(config["molecules"], 5, 32)
-    params = H.make_weights(config["model"], 5)
+    params = H.make_weights(H.family_of(config), config["model"], 5)
     out = {p: bench_reference.reference_outputs(
         params, config["model"], mols, node_pad=64, edge_pad=128,
         block_graphs=32, precision=p).astype(np.float64)
