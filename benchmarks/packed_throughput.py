@@ -142,7 +142,7 @@ def run_all(convs=None, dataset: str = "qm9",
     """Sweep every conv and record per-conv fused/unfused graphs/s —
     the perf-trajectory seed for the fused edge pipeline."""
     if convs is None:
-        convs = Cv.CONV_TYPES          # registry-derived: gat included
+        convs = Cv.STACK_CONVS         # registry-derived: gat included
     res = {"dataset": dataset, "n_graphs": n_graphs,
            "batch_graphs": batch_graphs,
            "jax_backend": jax.default_backend(), "convs": {}}
@@ -160,8 +160,8 @@ if __name__ == "__main__":
     compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--convs", nargs="+",
-                    default=list(Cv.CONV_TYPES),
-                    choices=list(Cv.CONV_TYPES))
+                    default=list(Cv.STACK_CONVS),
+                    choices=list(Cv.STACK_CONVS))
     ap.add_argument("--dataset", default="qm9")
     ap.add_argument("--n", type=int, default=64)
     ap.add_argument("--batch-graphs", type=int, default=32)

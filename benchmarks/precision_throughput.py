@@ -146,7 +146,7 @@ def run(convs=None, n_graphs: int = 64,
     if smoke:
         convs = ("gcn",)
     elif convs is None:
-        convs = Cv.CONV_TYPES          # registry-derived: gat included
+        convs = Cv.STACK_CONVS         # registry-derived: gat included
     res = {"dataset": "qm9", "n_graphs": n_graphs,
            "batch_graphs": batch_graphs,
            "jax_backend": jax.default_backend(),
@@ -186,8 +186,8 @@ if __name__ == "__main__":
                     help="gcn-only point + acceptance gates (parity per "
                          "precision, >= 1.5x modeled-bytes cut)")
     ap.add_argument("--convs", nargs="+",
-                    default=list(Cv.CONV_TYPES),
-                    choices=list(Cv.CONV_TYPES))
+                    default=list(Cv.STACK_CONVS),
+                    choices=list(Cv.STACK_CONVS))
     ap.add_argument("--n", type=int, default=64)
     ap.add_argument("--batch-graphs", type=int, default=32)
     ap.add_argument("--repeats", type=int, default=3)

@@ -167,7 +167,7 @@ def parity_phase(convs=None, batch_graphs: int = BATCH_GRAPHS) -> None:
     host_batch = {key: v for key, v in host_batch.items() if key != "y"}
     batch = jax.device_put(host_batch, dev)
     worst = {}
-    for conv in convs or Cv.CONV_TYPES:
+    for conv in convs or Cv.STACK_CONVS:
         cfg = serve.gnn_model_config(serve_args("--conv", conv))
         params = prm.materialize(G.model_plan(cfg),
                                  jax.random.key(SEED))

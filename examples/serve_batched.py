@@ -27,7 +27,7 @@ ap.add_argument("--gen", type=int, default=48)
 ap.add_argument("--gnn", action="store_true",
                 help="packed GraphBatch GNN serving instead of LM decode")
 ap.add_argument("--conv", default="gcn",
-                choices=list(Cv.CONV_TYPES))
+                choices=list(Cv.STACK_CONVS))
 ap.add_argument("--requests", type=int, default=256)
 ap.add_argument("--batch-graphs", type=int, default=32)
 ap.add_argument("--precision", default="fp32",

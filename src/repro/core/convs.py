@@ -7,7 +7,9 @@ anisotropic layers (PNA) that SpMM accelerators cannot express.
 Kernels: GCN [23], GraphSAGE [24], GIN(E) [26], PNA [27] — the paper's
 Table II set — plus GAT [25], the attention conv the GNN-acceleration
 survey names as the standard coverage axis (per-edge softmax is a new
-reduction shape: ``kernels/segment_softmax``). Each provides
+reduction shape: ``kernels/segment_softmax``), and GatedGCN
+(arXiv:1711.07553), the local conv of GraphGPS, which carries an edge
+state from layer to layer (``ConvSpec.edge_state``). Each provides
 ``plan(cfg)`` + ``apply(params, g, x)``, where ``g`` is a dict
 {edge_index (E,2), edge_feat (E,Fe), num_nodes, in_deg, out_deg,
 valid_e} with static max shapes (MAX_NODES/MAX_EDGES analogue).
@@ -18,7 +20,8 @@ conv's (plan, apply) pair and capability flags in ``CONV_REGISTRY``
 residency rule, ``dse.SPACE["conv"]``, the perf-model conv one-hots,
 and the test parity grids — enumerates convs from the registry. The
 legacy ``CONV_TYPES`` / ``REORDERABLE_CONVS`` / ``RESIDENT_CONVS``
-tuples survive as registry-derived views.
+tuples survive as registry-derived views, beside ``STACK_CONVS``, the
+convs a plain conv stack runs.
 
 The same applies serve both execution formats: a single padded graph and
 a packed GraphBatch (many graphs in one flat buffer). A packed batch is
@@ -99,6 +102,12 @@ class ConvSpec:
     partition_bitwise: bool = False
     # enumerated in dse.SPACE["conv"] / perf-model conv one-hots
     dse: bool = True
+    # takes an edge state (``g["edge_state"]``, the raw ``edge_feat`` when
+    # absent) and returns ``(node_out, edge_out)``; ``ConvConfig.edge_dim``
+    # is the width of the state it takes. Only a block that defines the
+    # state's update runs it (GraphGPS, ``core/gps.py``); a plain conv
+    # stack refuses it, so it is not in ``STACK_CONVS``
+    edge_state: bool = False
 
 
 CONV_REGISTRY: dict[str, ConvSpec] = {}
@@ -110,11 +119,14 @@ _REGISTRY_LISTENERS: list = []
 CONV_TYPES: tuple = ()
 REORDERABLE_CONVS: tuple = ()
 RESIDENT_CONVS: tuple = ()
+STACK_CONVS: tuple = ()
 
 
 def _registry_changed():
-    global CONV_TYPES, REORDERABLE_CONVS, RESIDENT_CONVS
+    global CONV_TYPES, REORDERABLE_CONVS, RESIDENT_CONVS, STACK_CONVS
     CONV_TYPES = tuple(CONV_REGISTRY)
+    STACK_CONVS = tuple(n for n, s in CONV_REGISTRY.items()
+                        if not s.edge_state)
     REORDERABLE_CONVS = tuple(n for n, s in CONV_REGISTRY.items()
                               if s.reorderable)
     RESIDENT_CONVS = tuple(n for n, s in CONV_REGISTRY.items()
@@ -545,6 +557,45 @@ def gat_apply(params, g, x, cfg: ConvConfig):
         + params["w"]["b"]
 
 
+# ------------------------------------------------------------ GatedGCN ---
+def gatedgcn_plan(cfg: ConvConfig, dtype=jnp.float32):
+    p = {k: linear_plan(cfg.in_dim, cfg.out_dim, in_axis="embed",
+                        out_axis="mlp", bias=True, dtype=dtype)
+         for k in ("A", "B", "D", "E")}
+    if cfg.edge_dim:
+        p["C"] = linear_plan(cfg.edge_dim, cfg.out_dim, in_axis=None,
+                             out_axis="mlp", bias=True, dtype=dtype)
+    return p
+
+
+def gatedgcn_apply(params, g, x, cfg: ConvConfig):
+    """Residual gated graph conv, with ``i`` the destination and ``j``
+    the source of each edge:
+    ``e'_ij = D x_i + E x_j + C e_ij``, ``s_ij = sigmoid(e'_ij)``,
+    ``x'_i = A x_i + sum_j s_ij * B x_j / (sum_j s_ij + 1e-6)``.
+
+    The gate is per edge and per channel, so it cannot ride the fused
+    gather tier's one scalar per edge: messages and gates materialise
+    and one segment sum over ``[s * B x_j ; s]`` gives numerator and
+    denominator (PNA's path), that stream at the layer's width. Returns
+    ``(x', e')``, ``e'`` before any activation."""
+    src, dst = edge_endpoints(g)
+    n, f = x.shape[0], cfg.out_dim
+    e = g.get("edge_state", g.get("edge_feat"))
+    e_hat = _gather(linear(params["D"], x), dst) \
+        + _gather(linear(params["E"], x), src)
+    if "C" in params:
+        e_hat = e_hat + linear(params["C"], e.astype(x.dtype))
+    gate = jax.nn.sigmoid(e_hat.astype(jnp.float32))
+    msg = gate * _gather(linear(params["B"], x), src).astype(jnp.float32)
+    sums = agg_mod.segment_aggregate(
+        "sum", jnp.concatenate([msg, gate], axis=-1), dst, n, g["valid_e"],
+        precision=cfg.precision).astype(jnp.float32)
+    out = linear(params["A"], x).astype(jnp.float32) \
+        + sums[:, :f] / (sums[:, f:] + 1e-6)
+    return out.astype(x.dtype), e_hat
+
+
 register_conv("gcn", gcn_plan, gcn_apply, reorderable=True, resident=True,
               partition_bitwise=True)
 register_conv("sage", sage_plan, sage_apply, reorderable=True,
@@ -553,6 +604,8 @@ register_conv("gin", gin_plan, gin_apply)
 register_conv("pna", pna_plan, pna_apply)
 register_conv("gat", gat_plan, gat_apply, attention=True,
               partition_bitwise=True)
+register_conv("gatedgcn", gatedgcn_plan, gatedgcn_apply, edge_state=True,
+              dse=False)
 
 
 def conv_plan(cfg: ConvConfig, dtype=jnp.float32):

@@ -4,7 +4,11 @@ GNN backbone (conv layers + activation + optional skip connections) ->
 global pooling (concat of sum/mean/max) -> MLP prediction head. Node- and
 graph-level tasks; node and edge input features; arbitrary activation;
 per-layer parallelism factors (gnn_p_in/hidden/out, mlp p_in/hidden/out)
-which map to kernel tile sizes on TPU.
+which map to kernel tile sizes on TPU. A configuration with ``gps`` set
+swaps the conv stack for GraphGPS blocks (``core/gps.py``: encoders, a
+local conv with an edge state plus per-graph self-attention, a
+feed-forward block, BatchNorm); the choice is made at trace time, and a
+conv-stack model's program is unchanged.
 
 The paper's Listing-1 API shape is preserved: a single config object the
 user trains against (here: init/apply over padded graphs), handed to
@@ -35,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import convs as C
+from repro.core import gps as GPS
 from repro.core import quantization as Q
 from repro.core.pooling import global_pooling, segment_global_pooling
 from repro.nn.layers import act, linear, linear_plan
@@ -51,6 +56,15 @@ class MLPConfig:
     p_in: int = 1
     p_hidden: int = 1
     p_out: int = 1
+    # widths of the hidden layers one by one (GraphGPS's san_graph head
+    # halves them); empty: ``hidden_layers`` layers of ``hidden_dim``
+    hidden_dims: tuple = ()
+
+    @property
+    def dims(self) -> list:
+        hidden = list(self.hidden_dims) or \
+            [self.hidden_dim] * self.hidden_layers
+        return [self.in_dim] + hidden + [self.out_dim]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,7 +75,7 @@ class GNNModelConfig:
     gnn_hidden_dim: int = 64
     gnn_num_layers: int = 2
     gnn_output_dim: int = 64
-    gnn_conv: str = "gcn"           # any registered conv (convs.CONV_TYPES)
+    gnn_conv: str = "gcn"           # convs.STACK_CONVS, or GPS's local conv
     gnn_activation: str = "relu"
     gnn_skip_connection: bool = True
     global_pooling: tuple = ("add", "mean", "max")
@@ -81,8 +95,20 @@ class GNNModelConfig:
     # per-layer PrecisionPolicy by apply/apply_packed (or overridden by
     # their policy= argument with a calibrated policy)
     gnn_precision: str = "fp32"
+    # GraphGPS blocks in place of the conv stack (core/gps.py); the
+    # local conv is ``gnn_conv``, which must carry an edge state
+    gps: GPS.GPSConfig | None = None
 
     def conv_cfg(self, layer: int) -> C.ConvConfig:
+        if self.gps is not None:
+            d = self.gnn_hidden_dim
+            return C.ConvConfig(in_dim=d, out_dim=d, edge_dim=d,
+                                conv=self.gnn_conv,
+                                activation=self.gnn_activation,
+                                p_in=self.gnn_p_hidden,
+                                p_out=self.gnn_p_hidden,
+                                dataflow=self.gnn_dataflow,
+                                avg_degree=self.avg_degree)
         ind = self.graph_input_feature_dim if layer == 0 \
             else self.gnn_hidden_dim
         outd = self.gnn_output_dim if layer == self.gnn_num_layers - 1 \
@@ -104,8 +130,7 @@ class GNNModelConfig:
 
 
 def mlp_head_plan(cfg: MLPConfig, dtype=jnp.float32):
-    dims = [cfg.in_dim] + [cfg.hidden_dim] * cfg.hidden_layers \
-        + [cfg.out_dim]
+    dims = cfg.dims
     return {f"l{i}": linear_plan(dims[i], dims[i + 1], in_axis=None,
                                  out_axis=None, bias=True, dtype=dtype)
             for i in range(len(dims) - 1)}
@@ -126,7 +151,7 @@ def mlp_head_apply(params, x, cfg: MLPConfig, quant: Q.FPX | None = None,
     if lp is not None and lp.compute != "fp32":
         params = lp.cast_params(params)
         x = lp.cast_activation(x)
-    n = cfg.hidden_layers + 1
+    n = len(cfg.dims) - 1
     for i in range(n):
         x = linear(params[f"l{i}"], x)
         if quant is not None:
@@ -141,6 +166,11 @@ def mlp_head_apply(params, x, cfg: MLPConfig, quant: Q.FPX | None = None,
 
 
 def model_plan(cfg: GNNModelConfig, dtype=jnp.float32):
+    if cfg.gps is not None:
+        plan = GPS.gps_plan(cfg, dtype)
+        if cfg.task == "graph":
+            plan["mlp"] = mlp_head_plan(cfg.mlp_head, dtype)
+        return plan
     plan = {"convs": {f"c{i}": C.conv_plan(cfg.conv_cfg(i), dtype)
                       for i in range(cfg.gnn_num_layers)}}
     if cfg.gnn_skip_connection:
@@ -330,6 +360,11 @@ def _backbone(params, cfg: GNNModelConfig, g, x, node_mask,
     which overwrites replicated boundary rows with their owners' values
     so layer i+1 aggregates over up-to-date neighbors.
     """
+    if C.conv_spec(cfg.gnn_conv).edge_state:
+        raise NotImplementedError(
+            f"{cfg.gnn_conv!r} carries an edge state, whose update a plain "
+            "conv stack does not define: run it as the local conv of "
+            "GraphGPS blocks (GNNModelConfig.gps)")
     nl = cfg.gnn_num_layers
     for i in range(nl):
         with jax.named_scope(f"conv{i}"):
@@ -361,6 +396,16 @@ def _backbone(params, cfg: GNNModelConfig, g, x, node_mask,
     return x
 
 
+def _gps(params, cfg: GNNModelConfig, g, x, node_mask, graph_id,
+         graph_num_nodes, quant, policy):
+    """The GPS backbone in place of ``_backbone``."""
+    if quant is not None:
+        raise NotImplementedError("GPS models have no fixed-point "
+                                  "testbench semantics")
+    return GPS.gps_backbone(params, cfg, g, x, node_mask, graph_id,
+                            graph_num_nodes, policy)
+
+
 def apply(params, cfg: GNNModelConfig, batch_el: dict,
           quant: Q.FPX | None = None, policy=None):
     """Forward one padded graph. quant != None reproduces the fixed-point
@@ -370,9 +415,14 @@ def apply(params, cfg: GNNModelConfig, batch_el: dict,
     pol = resolve_policy(cfg, policy)
     pol = None if pol.is_fp32 else pol
     g, x, node_mask = graph_inputs(batch_el)
-    if quant is not None:
-        x = Q.quantize(x, quant)
-    x = _backbone(params, cfg, g, x, node_mask, quant, pol)
+    if cfg.gps is not None:
+        x = _gps(params, cfg, g, x, node_mask,
+                 jnp.where(node_mask, 0, 1).astype(jnp.int32),
+                 jnp.reshape(batch_el["num_nodes"], (1,)), quant, pol)
+    else:
+        if quant is not None:
+            x = Q.quantize(x, quant)
+        x = _backbone(params, cfg, g, x, node_mask, quant, pol)
     if cfg.task == "node":
         return x
     pooled = global_pooling(cfg.global_pooling, x, node_mask)
@@ -410,10 +460,18 @@ def apply_packed(params, cfg: GNNModelConfig, batch: dict,
     pol = None if pol.is_fp32 else pol
     g, x, node_mask, graph_id = packed_inputs(batch)
     num_graphs = batch["graph_valid"].shape[0]
-    if quant is not None:
-        x = Q.quantize(x, quant)
-    x = _backbone(params, cfg, g, x, node_mask, quant, pol,
-                  exchange=halo_exchange)
+    if cfg.gps is not None:
+        if halo_exchange is not None:
+            raise NotImplementedError(
+                "a GPS model attends over whole graphs: it cannot run "
+                "on a graph partitioned over devices")
+        x = _gps(params, cfg, g, x, node_mask, graph_id,
+                 batch["graph_num_nodes"], quant, pol)
+    else:
+        if quant is not None:
+            x = Q.quantize(x, quant)
+        x = _backbone(params, cfg, g, x, node_mask, quant, pol,
+                      exchange=halo_exchange)
     if cfg.task == "node" or return_node_features:
         return x
     with jax.named_scope("pooling"):

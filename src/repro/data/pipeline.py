@@ -18,6 +18,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.runtime import trace
+
 
 @dataclasses.dataclass(frozen=True)
 class TokenDataConfig:
@@ -208,6 +210,10 @@ def pack_graphs(graphs, node_budget: int, edge_budget: int,
     ``max_graphs``), keeping dataset order so output row i corresponds to
     graphs[i]. Returns (batch, n_packed). Raises ValueError if graphs[0]
     alone exceeds the budget — the caller must drop or resize.
+
+    While a profiler session runs, counts the per-graph attention work
+    the batch gives (``attn.*``, ``count_segment_attention``): a pure
+    function of the graph sizes, whatever model runs the batch.
     """
     if not graphs:
         raise ValueError("pack_graphs needs at least one graph")
@@ -238,6 +244,9 @@ def pack_graphs(graphs, node_budget: int, edge_budget: int,
         e_used += e
         k += 1
     batch["num_graphs"] = np.int32(k)
+    if trace.enabled():
+        from repro.kernels.flash_attention.ops import count_segment_attention
+        count_segment_attention(batch["graph_num_nodes"], node_budget)
     return batch, k
 
 

@@ -588,7 +588,7 @@ def parse_args(argv=None):
     ap.add_argument("--gnn", action="store_true",
                     help="serve packed GraphBatch GNN inference")
     ap.add_argument("--conv", default="gcn",
-                    choices=list(Cv.CONV_TYPES))
+                    choices=list(Cv.STACK_CONVS))
     ap.add_argument("--requests", type=int, default=256)
     ap.add_argument("--oversize-requests", type=int, default=0,
                     help="append N giant graphs (~2x the node budget) to "

@@ -34,6 +34,13 @@ from jax.profiler import TraceAnnotation
 _on = TraceAnnotation.is_enabled
 
 
+def enabled() -> bool:
+    """Whether spans and counters record now: for a caller whose counter
+    costs work to compute, so that it skips the work outside a profiler
+    session."""
+    return _on()
+
+
 class _NoSpan:
     __slots__ = ()
 

@@ -8,7 +8,9 @@ Before this module the three suites each hardcoded their own
 adding a conv meant editing every test file and hoping none was missed.
 Now the axes come from ``repro.core.convs.CONV_REGISTRY``:
 
-* ``conv_axis()`` — every registered conv, in registration order;
+* ``conv_axis()`` — every registered conv a plain conv stack runs
+  (``STACK_CONVS``: not the edge-state convs, which only GraphGPS
+  blocks run, tests/test_gps.py), in registration order;
 * ``precision_axis(conv)`` — the precisions its ConvSpec declares
   (attention convs still list int8: only the projection and the
   aggregation stream quantize, the attention math itself is pinned to
@@ -42,9 +44,9 @@ ORACLE_ATOL = 1e-4          # fp32 packed vs the padded per-graph oracle
 
 
 def conv_axis():
-    """Every registered conv — the rows of the parity matrix."""
+    """Every conv-stack conv — the rows of the parity matrix."""
     from repro.core import convs as Cv
-    return tuple(Cv.CONV_TYPES)
+    return tuple(Cv.STACK_CONVS)
 
 
 def precision_axis(conv):
@@ -61,7 +63,7 @@ def conv_precision_cases():
 def bitwise_convs():
     """Convs promising bitwise fp32 partitioned parity."""
     from repro.core import convs as Cv
-    return tuple(n for n in Cv.CONV_TYPES
+    return tuple(n for n in Cv.STACK_CONVS
                  if Cv.conv_spec(n).partition_bitwise)
 
 
@@ -146,7 +148,7 @@ SCRIPT_PRELUDE = textwrap.dedent("""
     from repro.nn import param as prm
     from repro.core import aggregations as agg_mod
 
-    CONVS = tuple(Cv.CONV_TYPES)
+    CONVS = tuple(Cv.STACK_CONVS)
     BITWISE = tuple(n for n in CONVS
                     if Cv.conv_spec(n).partition_bitwise)
 
@@ -181,6 +183,7 @@ def sharded_parity_script():
 
     import tempfile
     from jax.sharding import NamedSharding, PartitionSpec as PS
+    from repro.kernels.flash_attention import ops as AO
     from repro.launch import serve
     from repro.runtime import trace
 
@@ -240,7 +243,17 @@ def sharded_parity_script():
             ref = P.gather_shard_outputs(
                 np.asarray(fn(params, per_leaf_stack(w))), w.index)
             assert np.array_equal(o, ref), conv
-        assert counters == {"put.buffers": len(waves)}, counters
+        # one buffer a wave; the packer counts each shard it packs
+        # (empty shards are not packed)
+        sizes = [np.asarray(b["graph_num_nodes"], np.int64)
+                 for w in waves for b, ix in zip(w.shards, w.index) if ix]
+        computed = sum(int(AO.segment_tiles(n, 128, 128)[1].sum())
+                       for n in sizes)
+        assert counters == {
+            "put.buffers": len(waves),
+            "attn.rows": int(sum(n.sum() for n in sizes)),
+            "attn.pairs": int(sum((n * n).sum() for n in sizes)),
+            "attn.pairs_computed": computed * 128 * 128}, counters
         # 4-shard wave with idle shards: one graph, three empty blocks
         wave4, k4 = P.shard_pack(graphs[:1], 96, 192, 8, num_shards=4)
         assert k4 == 1

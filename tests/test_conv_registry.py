@@ -24,6 +24,8 @@ def test_capability_tuples_derive_from_registry():
         n for n in Cv.CONV_TYPES if Cv.conv_spec(n).reorderable)
     assert Cv.RESIDENT_CONVS == tuple(
         n for n in Cv.CONV_TYPES if Cv.conv_spec(n).resident)
+    assert Cv.STACK_CONVS == tuple(
+        n for n in Cv.CONV_TYPES if not Cv.conv_spec(n).edge_state)
     # the attention conv is registered with the documented capabilities
     gat = Cv.conv_spec("gat")
     assert gat.attention and gat.partition_bitwise
@@ -41,7 +43,7 @@ def test_dse_space_perf_features_and_registry_agree():
     assert dse.SPACE["conv"] == dse_convs
     onehots = [f for f in PM.FEATURE_NAMES if f.startswith("conv_")]
     assert onehots == [f"conv_{c}" for c in dse_convs]
-    assert parity.conv_axis() == tuple(Cv.CONV_TYPES)
+    assert parity.conv_axis() == tuple(Cv.STACK_CONVS)
     # featurization one-hot roundtrip, gat included
     rng = np.random.default_rng(0)
     d = dict(dse.sample_design(rng), conv="gat")

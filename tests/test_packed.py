@@ -8,7 +8,7 @@ import pytest
 
 from repro.core import aggregations as A
 from repro.core import gnn_model as G
-from repro.core.convs import CONV_TYPES
+from repro.core.convs import STACK_CONVS
 from repro.core.pooling import POOLINGS, global_pool, segment_global_pool
 from repro.data import pipeline as P
 from repro.nn import param as prm
@@ -60,7 +60,7 @@ def _pack(graphs, max_graphs=8):
 
 
 # -------------------------------------------------- model equivalence ---
-@pytest.mark.parametrize("conv", CONV_TYPES)
+@pytest.mark.parametrize("conv", STACK_CONVS)
 def test_apply_packed_matches_apply(conv):
     """One jitted packed program == the per-graph padded oracle, for every
     conv type, including an empty-edge graph mid-batch."""
@@ -76,7 +76,7 @@ def test_apply_packed_matches_apply(conv):
         assert float(np.mean(np.abs(out[i] - ref))) < 1e-4, (conv, i)
 
 
-@pytest.mark.parametrize("conv", CONV_TYPES)
+@pytest.mark.parametrize("conv", STACK_CONVS)
 def test_apply_packed_node_task(conv):
     cfg = _cfg(conv, task="node")
     params = prm.materialize(G.model_plan(cfg), jax.random.key(1))

@@ -240,7 +240,7 @@ def test_all_padding_edge_blocks_every_precision(agg, precision):
 
 
 # ---------------------------------------- model-level precision parity --
-@pytest.mark.parametrize("conv", C.CONV_TYPES)
+@pytest.mark.parametrize("conv", C.STACK_CONVS)
 def test_bf16_policy_within_documented_tolerance(conv):
     """apply_packed under the bf16 policy vs the fp32 oracle: <= 5e-2
     max-abs on the reduced config (the KERNELS.md tolerance table)."""
@@ -256,7 +256,7 @@ def test_bf16_policy_within_documented_tolerance(conv):
 
 
 @pytest.mark.parametrize("precision", ["bf16", "int8"])
-@pytest.mark.parametrize("conv", C.CONV_TYPES)
+@pytest.mark.parametrize("conv", C.STACK_CONVS)
 def test_packed_backend_parity_per_precision(conv, precision):
     """XLA vs Pallas trace of the same low-precision policy agree to
     fp32 tolerance for every conv — including the empty-edge graph and

@@ -283,3 +283,51 @@ def test_partitioned_program_compiles_on_4_devices(mesh4):
     c = _compile(fn, _param_specs(cfg, NamedSharding(mesh4, PS())),
                  _specs(stacked, NamedSharding(mesh4, PS("data"))))
     assert "all-gather" in c.as_text()
+
+
+# ------------------------------------------------------------ GraphGPS --
+# the gps-peptides cell: 128-graph batches of 22,272 node rows, 4 heads
+# of 24
+GPS_N, GPS_E, GPS_G = 22272, 45240, 128
+
+
+def test_segment_attention_compiles_at_the_cell_shapes(one_chip):
+    from repro.kernels.flash_attention.kernel import \
+        segment_attention_pallas
+    tiles = GPS_N // 128
+    rows = _spec((GPS_N, 96), jnp.float32, one_chip)
+    c = _compile(
+        lambda q, k, v, g, f, n: segment_attention_pallas(
+            q, k, v, g, f, n, num_heads=4, num_graphs=GPS_G,
+            interpret=False),
+        rows, rows, rows, _spec((GPS_N,), jnp.int32, one_chip),
+        _spec((tiles,), jnp.int32, one_chip),
+        _spec((tiles,), jnp.int32, one_chip))
+    _kernel_ok(c)
+
+
+def test_gps_program_compiles_with_the_kernel(one_chip, monkeypatch):
+    """The whole GPS program at the cell's shapes, with the attention
+    on the kernel as it runs on a TPU: four kernel calls, one a layer,
+    all named ``segment_attention`` (the op label the benchmark's
+    ``attn_ms.screen`` reads)."""
+    import functools
+    import re
+
+    from repro.core import gnn_model as G
+    from repro.core import gps as GPS
+    from repro.data import pipeline as P
+    monkeypatch.setattr(GPS.attn_ops, "segment_attention", functools.partial(
+        GPS.attn_ops.segment_attention, use_kernel=True))
+    cfg = G.GNNModelConfig(
+        graph_input_feature_dim=39, graph_input_edge_dim=3,
+        gnn_hidden_dim=96, gnn_num_layers=4, gnn_output_dim=96,
+        gnn_conv="gatedgcn", global_pooling=("mean",),
+        mlp_head=G.MLPConfig(in_dim=96, out_dim=10, hidden_dims=(48, 24)),
+        gps=GPS.GPSConfig())
+    batch = {k: v for k, v in P.empty_graph_batch(
+        GPS_N, GPS_E, GPS_G, 39, 3, 10).items() if k != "y"}
+    c = _compile(lambda p, b: G.apply_packed(p, cfg, b),
+                 _param_specs(cfg, one_chip), _specs(batch, one_chip))
+    names = re.findall(r"%(segment_attention[.\d]*) = ", c.as_text())
+    assert len(names) == 4, names
