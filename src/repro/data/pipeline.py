@@ -348,9 +348,49 @@ def gather_shard_outputs(outs, index) -> np.ndarray:
     return host
 
 
+def iter_pack(graphs, node_budget: int, edge_budget: int, max_graphs: int,
+              *, dropped: list, num_shards: int = 1):
+    """The greedy packer of ``pack_dataset``, one batch at a time: yields
+    the batches ``pack_dataset`` returns, in order, while pulling from
+    the iterable ``graphs`` only as far as the next batch needs.
+
+    A batch is ``pack_graphs`` (``shard_pack`` when ``num_shards > 1``)
+    on the graphs not yet packed, and neither looks past the first
+    ``max_graphs * num_shards`` of them, so that many held back are
+    enough to close a batch exactly as over the whole list. A graph that
+    can never fit the budgets on its own closes the batch in progress
+    (a greedy pack stops at it), and is appended to ``dropped``."""
+    ahead = max_graphs * num_shards
+    held: list = []
+
+    def pack():
+        nonlocal held
+        if num_shards > 1:
+            batch, k = shard_pack(held, node_budget, edge_budget,
+                                  max_graphs, num_shards)
+        else:
+            batch, k = pack_graphs(held, node_budget, edge_budget,
+                                   max_graphs)
+        held = held[k:]
+        return batch
+
+    for g in graphs:
+        if not graph_fits_budget(g, node_budget, edge_budget):
+            while held:
+                yield pack()
+            dropped.append(g)
+            continue
+        held.append(g)
+        if len(held) == ahead:
+            yield pack()
+    while held:
+        yield pack()
+
+
 def pack_dataset(graphs, node_budget: int, edge_budget: int,
                  max_graphs: int, num_shards: int = 1) -> tuple:
-    """Pack an entire dataset into a list of GraphBatch dicts.
+    """Pack an entire dataset into a list of GraphBatch dicts
+    (``iter_pack`` run to its end).
 
     Graphs that can never fit the budget on their own are returned in
     ``dropped`` instead of stalling the stream. Order is preserved:
@@ -363,22 +403,9 @@ def pack_dataset(graphs, node_budget: int, edge_budget: int,
     waves' ``gather_shard_outputs`` results visits the non-dropped
     graphs in dataset order.
     """
-    batches, dropped = [], []
-    i = 0
-    while i < len(graphs):
-        if not graph_fits_budget(graphs[i], node_budget, edge_budget):
-            dropped.append(graphs[i])
-            i += 1
-            continue
-        if num_shards > 1:
-            wave, k = shard_pack(graphs[i:], node_budget, edge_budget,
-                                 max_graphs, num_shards)
-            batches.append(wave)
-        else:
-            batch, k = pack_graphs(graphs[i:], node_budget, edge_budget,
-                                   max_graphs)
-            batches.append(batch)
-        i += k
+    dropped: list = []
+    batches = list(iter_pack(graphs, node_budget, edge_budget, max_graphs,
+                             dropped=dropped, num_shards=num_shards))
     return batches, dropped
 
 

@@ -84,7 +84,7 @@ def _fallback_input(g) -> dict:
 
 def _admit(queue, node_budget: int, edge_budget: int, *,
            can_fallback: bool, can_partition: bool = False,
-           validate: bool = True):
+           validate: bool = True, first: int = 0):
     """Admission screen of the wave drains, mirroring the continuous
     scheduler's ``submit``: every request is routed to exactly one
     outcome up front — packable, oversize (answered by the partitioned
@@ -99,11 +99,12 @@ def _admit(queue, node_budget: int, edge_budget: int, *,
     ``outcomes[i]`` carries the queue index, the status
     (continuous-scheduler status names — oversize statuses are the
     *planned* route, reconciled to the actual one after launch), and a
-    reason for rejections."""
+    reason for rejections; ``first`` is the queue index of ``queue[0]``
+    when ``queue`` is one chunk of a longer queue."""
     from repro.data import pipeline as P
     from repro.runtime import scheduler as S
     packable, oversize, outcomes = [], [], []
-    for i, g in enumerate(queue):
+    for i, g in enumerate(queue, first):
         if validate:
             reason = P.validate_graph(g)
             if reason is not None:
@@ -155,21 +156,24 @@ def _rejection_stats(stats: dict, outcomes) -> dict:
 
 
 def _launch_packed(run_batch, batches, oversize, fallback_fn, *,
-                   graphs_in, slots_in, slot_capacity: int,
+                   graphs_in, slots_in, slots_per_batch: int,
                    partition_fn=None):
     """Shared pack-and-launch body of the wave drains (and of anything
-    else that runs a prepacked batch list): run every batch through
-    ``run_batch``, answer oversize requests through ``partition_fn``
-    (the intra-graph partitioned SPMD program; returns None when the
-    graph cannot split under the per-device budgets) and ``fallback_fn``
-    (the padded per-graph oracle on a ``_fallback_input`` dict), block,
-    and account. Each oversize graph resolves to exactly one of
-    partitioned / fallback / rejected-oversize — never double-counted.
-    ``graphs_in``/``slots_in`` count the graphs and occupied node slots
-    of one batch (they differ between the single-device and sharded
-    layouts). Returns (batch_outs, oversize_outs, oversize_statuses,
-    stats); ``oversize_outs``/``oversize_statuses`` line up with
-    ``oversize`` (rejected graphs carry a None output)."""
+    else that runs a prepacked batch list): run every batch of the
+    iterable ``batches`` through ``run_batch`` as it comes, answer
+    oversize requests through ``partition_fn`` (the intra-graph
+    partitioned SPMD program; returns None when the graph cannot split
+    under the per-device budgets) and ``fallback_fn`` (the padded
+    per-graph oracle on a ``_fallback_input`` dict), block, and account.
+    ``oversize`` is read once ``batches`` is exhausted, so a lazy
+    ``batches`` may still be adding to it. Each oversize graph resolves
+    to exactly one of partitioned / fallback / rejected-oversize — never
+    double-counted. ``graphs_in``/``slots_in`` count the graphs and
+    occupied node slots of one batch, and ``slots_per_batch`` its node
+    slots (they differ between the single-device and sharded layouts).
+    Returns (batch_outs, oversize_outs, oversize_statuses, stats);
+    ``oversize_outs``/``oversize_statuses`` line up with ``oversize``
+    (rejected graphs carry a None output)."""
     from repro.runtime import scheduler as S
     outs = []
     served = 0
@@ -201,10 +205,23 @@ def _launch_packed(run_batch, batches, oversize, fallback_fn, *,
         "packed_served": served,
         "partitioned_served": n_part,
         "fallback_served": n_fallback,
-        "n_batches": len(batches),
-        "node_slot_utilization": slots_used / max(slot_capacity, 1),
+        "n_batches": len(outs),
+        "node_slot_utilization":
+            slots_used / max(len(outs) * slots_per_batch, 1),
     }
     return outs, over_outs, over_status, stats
+
+
+def _spanned(it, name: str):
+    """Yield the items of iterator ``it``, each one's work under span
+    ``name``; the span closes before the item is handed on."""
+    while True:
+        with trace.span(name):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
 
 
 def _timed(stats: dict, t0: float) -> dict:
@@ -241,6 +258,15 @@ def drain_gnn_queue(fn, params, queue, node_budget: int, edge_budget: int,
     scheduler uses, and ``stats["dropped"]`` stays as a legacy alias of
     ``rejected_oversize``.
 
+    The drain is software-pipelined on one device: it admits the queue
+    in chunks of ``batch_graphs`` requests and packs the admitted ones
+    in queue order (``P.iter_pack``), and each batch is launched as soon
+    as it is packed, so the host admits and packs batch k+1 while the
+    device runs batch k. The batches are those ``pack_dataset`` gives on
+    the whole admitted list. Under a profiler session the counter
+    ``serve.launch_ahead`` counts the launches made before the last
+    request was admitted.
+
     This drain is the offline-throughput baseline (and parity oracle)
     for the continuous-batching scheduler — see
     ``drain_gnn_queue_continuous`` for the latency-aware path."""
@@ -248,23 +274,42 @@ def drain_gnn_queue(fn, params, queue, node_budget: int, edge_budget: int,
     from repro.data import pipeline as P
     t0 = time.perf_counter()
     with trace.span("serve.drain", graphs=len(queue)):
-        with trace.span("serve.admit"):
-            packable, oversize, outcomes = _admit(
-                queue, node_budget, edge_budget,
-                can_fallback=fallback_fn is not None,
-                can_partition=partition_fn is not None, validate=validate)
-        with trace.span("pack.dataset"):
-            batches, leftover = P.pack_dataset(packable, node_budget,
-                                               edge_budget, batch_graphs)
-        assert not leftover, "_admit already screened for budget fit"
+        oversize, outcomes, leftover = [], [], []
+        admitted_all = False
+
+        def admitted():
+            nonlocal admitted_all
+            for lo in range(0, len(queue), batch_graphs):
+                with trace.span("serve.admit"):
+                    packable, over, chunk = _admit(
+                        queue[lo:lo + batch_graphs], node_budget,
+                        edge_budget, can_fallback=fallback_fn is not None,
+                        can_partition=partition_fn is not None,
+                        validate=validate, first=lo)
+                oversize.extend(over)
+                outcomes.extend(chunk)
+                admitted_all = lo + batch_graphs >= len(queue)
+                yield from packable
+
+        def run_batch(b):
+            if not admitted_all:
+                trace.count("serve.launch_ahead")
+            return fn(params, G.packed_to_device(b))
+
+        # admission runs inside the packer's pulls, so each serve.admit
+        # nests in a pack.dataset, whose self time is the packing
+        batches = _spanned(P.iter_pack(admitted(), node_budget, edge_budget,
+                                       batch_graphs, dropped=leftover),
+                           "pack.dataset")
         outs, over_outs, over_status, stats = _launch_packed(
-            lambda b: fn(params, G.packed_to_device(b)), batches, oversize,
+            run_batch, batches, oversize,
             None if fallback_fn is None
             else (lambda el: fallback_fn(params, el)),
             partition_fn=partition_fn,
             graphs_in=lambda b: int(b["num_graphs"]),
             slots_in=lambda b: int((b["node_graph_id"] < batch_graphs).sum()),
-            slot_capacity=len(batches) * node_budget)
+            slots_per_batch=node_budget)
+        assert not leftover, "_admit already screened for budget fit"
         _reconcile_oversize(outcomes, over_status)
         stats = _rejection_stats(_timed(stats, t0), outcomes)
     return outs + [o for o in over_outs if o is not None], stats
@@ -313,7 +358,7 @@ def drain_gnn_queue_sharded(fn, params, queue, node_budget: int,
             slots_in=lambda w: sum(int((b["node_graph_id"]
                                         < batch_graphs).sum())
                                    for b in w.shards),
-            slot_capacity=len(waves) * num_shards * node_budget)
+            slots_per_batch=num_shards * node_budget)
         stats["num_shards"] = num_shards
         _reconcile_oversize(outcomes, over_status)
         if task == "graph":

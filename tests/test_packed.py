@@ -1,6 +1,8 @@
 """Packed GraphBatch IR: packed-vs-padded equivalence for every conv type
 and aggregation (including isolated nodes and empty-edge graphs), packing
 invariants, budget overflow handling, and deterministic bucketing."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -208,6 +210,142 @@ def test_pack_dataset_partitions_and_respects_budgets():
             # graph ids are contiguous 0..k-1 in packing order
             ids = b["node_graph_id"][node_valid]
             assert (np.diff(ids) >= 0).all() and set(ids) == set(range(k))
+
+
+def _pack_whole_list(graphs, nb, eb, mg, num_shards=1):
+    """The greedy packer as a loop over the whole list: the reference
+    the incremental packer must reproduce batch for batch."""
+    batches, dropped = [], []
+    i = 0
+    while i < len(graphs):
+        if not P.graph_fits_budget(graphs[i], nb, eb):
+            dropped.append(graphs[i])
+            i += 1
+            continue
+        if num_shards > 1:
+            batch, k = P.shard_pack(graphs[i:], nb, eb, mg, num_shards)
+        else:
+            batch, k = P.pack_graphs(graphs[i:], nb, eb, mg)
+        batches.append(batch)
+        i += k
+    return batches, dropped
+
+
+def _shards(batch):
+    """A batch as its list of GraphBatch dicts (one, unless a wave)."""
+    return batch.shards if isinstance(batch, P.ShardedBatch) else [batch]
+
+
+def _assert_same_batches(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        if isinstance(w, P.ShardedBatch):
+            assert g.index == w.index
+        for gs, ws in zip(_shards(g), _shards(w), strict=True):
+            assert gs.keys() == ws.keys()
+            for key in ws:
+                np.testing.assert_array_equal(gs[key], ws[key], err_msg=key)
+                assert np.asarray(gs[key]).dtype == np.asarray(ws[key]).dtype
+
+
+@pytest.mark.parametrize("num_shards", [1, 2])
+def test_iter_pack_matches_the_whole_list_packer(num_shards):
+    """Over many budgets, with oversize graphs inside the stream, the
+    incremental packer fed from a generator gives the whole-list
+    packer's batches array by array and drops the same graphs; it holds
+    at most ``max_graphs * num_shards`` unpacked graphs when it yields;
+    and ``pack_dataset`` is the same list."""
+    rng = np.random.default_rng(11)
+    cfg = P.GraphDataConfig(avg_nodes=14, max_nodes=80, max_edges=120,
+                            node_feat_dim=5, edge_feat_dim=2, seed=3)
+    graphs = [P.make_graph(cfg, i) for i in range(40)]
+    drops = 0
+    for _ in range(10):
+        nb = int(rng.integers(16, 120))
+        eb = int(rng.integers(16, 200))
+        mg = int(rng.integers(1, 9))
+        want, want_dropped = _pack_whole_list(graphs, nb, eb, mg, num_shards)
+        pulled = []
+
+        def source():
+            for g in graphs:
+                pulled.append(g)
+                yield g
+
+        got, dropped, packed = [], [], 0
+        for batch in P.iter_pack(source(), nb, eb, mg, dropped=dropped,
+                                 num_shards=num_shards):
+            got.append(batch)
+            packed += sum(int(s["num_graphs"]) for s in _shards(batch))
+            assert len(pulled) - len(dropped) - packed <= mg * num_shards
+        _assert_same_batches(got, want)
+        assert [id(g) for g in dropped] == [id(g) for g in want_dropped]
+        drops += len(want_dropped)
+        batches, pd_dropped = P.pack_dataset(graphs, nb, eb, mg, num_shards)
+        _assert_same_batches(batches, want)
+        assert [id(g) for g in pd_dropped] == [id(g) for g in want_dropped]
+    assert drops                       # oversize graphs closed batches
+
+
+def _malformed(g):
+    """``g`` with an edge endpoint past its last node: ``validate_graph``
+    rejects it."""
+    ei = g.edge_index.copy()
+    ei[0, 1] = g.num_nodes
+    return P.Graph(g.node_feat, ei, g.edge_feat, g.num_nodes, g.num_edges,
+                   g.y)
+
+
+def test_pipelined_drain_matches_the_serial_order(monkeypatch):
+    """The drain launches each batch as soon as it is packed. Over a
+    queue of packable, oversize and malformed requests spread over
+    several batches, the batches it launches are ``pack_dataset`` on the
+    admitted list, array by array, and its outputs, outcomes and stats
+    are those of admitting the whole queue, packing it, then launching."""
+    from repro.launch import serve
+    nb, eb, mg = 48, 96, 4
+    big = dataclasses.replace(DS, avg_nodes=56)
+    queue = [P.make_graph(DS, i) for i in range(14)]
+    queue.insert(3, P.make_graph(big, 0))
+    queue.insert(6, _malformed(queue[5]))
+    queue.insert(9, P.make_graph(big, 2))
+    queue.insert(13, _malformed(queue[12]))
+    assert sum(not P.graph_fits_budget(g, nb, eb) for g in queue) == 2
+    cfg = _cfg("gcn")
+    params = prm.materialize(G.model_plan(cfg), jax.random.key(0))
+    fn = jax.jit(lambda p, b: G.apply_packed(p, cfg, b))
+    fb = jax.jit(lambda p, el: G.apply(p, cfg, el))
+
+    launched = []
+    to_device = G.packed_to_device
+
+    def recording(batch):
+        launched.append(batch)
+        return to_device(batch)
+
+    monkeypatch.setattr(G, "packed_to_device", recording)
+    outs, stats = serve.drain_gnn_queue(fn, params, queue, nb, eb, mg, fb)
+    monkeypatch.undo()
+
+    packable, oversize, outcomes = serve._admit(queue, nb, eb,
+                                                can_fallback=True)
+    batches, dropped = P.pack_dataset(packable, nb, eb, mg)
+    assert not dropped and len(batches) >= 3
+    _assert_same_batches(launched, batches)
+    serial = [fn(params, G.packed_to_device(b)) for b in batches]
+    serial += [fb(params, serve._fallback_input(g)) for g in oversize]
+    assert len(outs) == len(serial)
+    for got, want in zip(outs, serial):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert stats["outcomes"] == outcomes
+    assert [o["index"] for o in stats["outcomes"]] == list(range(len(queue)))
+    assert stats["n_batches"] == len(batches)
+    assert stats["rejected_invalid"] == 2
+    assert stats["fallback_served"] == 2
+    assert stats["served"] == len(packable) + 2
+    assert stats["node_slot_utilization"] == sum(
+        int((b["node_graph_id"] < mg).sum()) for b in batches) / (
+            len(batches) * nb)
 
 
 def test_pack_graphs_raises_on_oversize_first():

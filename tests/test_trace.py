@@ -141,6 +141,11 @@ def test_spans_land_in_the_profilers_host_plane(tmp_path):
 
 # ------------------------------------------------------ serving path --
 def test_drain_spans_one_per_stage_and_one_per_batch(program, tmp_path):
+    """The drain admits one chunk of ``MAX_GRAPHS`` requests at a time
+    inside the packer's pulls: one ``serve.admit`` per chunk, nested in
+    ``pack.dataset``, which opens once per batch and once more for the
+    pull that finds the queue done. Nothing but the transfer runs inside
+    a ``device.launch``."""
     queue = _queue()
     _drain(program, queue)                       # compile off the record
     with jax.profiler.trace(str(tmp_path)):
@@ -148,23 +153,60 @@ def test_drain_spans_one_per_stage_and_one_per_batch(program, tmp_path):
     spans = _spans()
     n = stats["n_batches"]
     assert n >= 2
-    for name in ("serve.drain", "serve.admit", "pack.dataset",
-                 "device.wait"):
+    for name in ("serve.drain", "device.wait"):
         assert spans[name]["n"] == 1, name
+    assert spans["serve.admit"]["n"] == -(-len(queue) // MAX_GRAPHS)
+    assert spans["pack.dataset"]["n"] == n + 1
     assert spans["device.put"]["n"] == n
     assert spans["device.launch"]["n"] == n
     assert "pack.gather_shards" not in spans
     assert not any(name.startswith("bench.") for name in spans)
     drain = spans["serve.drain"]
     children = sum(spans[k]["total_s"] for k in (
-        "serve.admit", "pack.dataset", "device.launch", "device.wait"))
+        "pack.dataset", "device.launch", "device.wait"))
     assert drain["self_s"] == pytest.approx(drain["total_s"] - children,
                                             abs=1e-9)
-    # device.put runs inside device.launch: the launch's self time
-    # leaves the transfer out
+    pack = spans["pack.dataset"]
+    assert pack["self_s"] == pytest.approx(
+        pack["total_s"] - sum(spans[k]["total_s"] for k in (
+            "serve.admit", "pack.attn_counts")), abs=1e-9)
+    # device.put runs inside device.launch, and nothing else does: the
+    # launch's self time leaves out the transfer alone
     launch = spans["device.launch"]
     assert launch["self_s"] == pytest.approx(
         launch["total_s"] - spans["device.put"]["total_s"], abs=1e-9)
+
+
+def test_drain_launches_before_the_last_request_is_admitted(
+        program, tmp_path, monkeypatch):
+    """The first batch is on its way before the queue's last request is
+    validated, and ``serve.launch_ahead`` counts every launch but the
+    last (each batch here closes at ``MAX_GRAPHS`` graphs)."""
+    fn, params = program
+    queue = _queue()
+    _drain(program, queue)                       # compile off the record
+    events = []
+    validate = P.validate_graph
+
+    def counting_validate(g):
+        events.append("validate")
+        return validate(g)
+
+    def counting_fn(p, b):
+        events.append("launch")
+        return fn(p, b)
+
+    monkeypatch.setattr(P, "validate_graph", counting_validate)
+    with jax.profiler.trace(str(tmp_path)):
+        _, stats = serve.drain_gnn_queue(counting_fn, params, queue,
+                                         NODE_BUDGET, EDGE_BUDGET,
+                                         MAX_GRAPHS)
+    n = stats["n_batches"]
+    assert n == len(queue) // MAX_GRAPHS >= 3
+    assert events.count("validate") == len(queue)
+    last_validate = len(events) - 1 - events[::-1].index("validate")
+    assert events.index("launch") < last_validate
+    assert trace.snapshot()["counters"]["serve.launch_ahead"] == n - 1
 
 
 def test_sharded_drain_spans_the_gather(tmp_path):
